@@ -65,20 +65,21 @@ from typing import Dict, List
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.api import PcclSession
 from repro.comm import exec_engine
 from repro.comm import primitives as prim
 from repro.core import cost_model as cm
+from repro.launch.cache import enable_compile_cache
 
 COLLECTIVES = ("reduce_scatter", "all_gather", "all_reduce", "all_to_all")
 HW = cm.TPU_V5E_PHOTONIC
 
 
 def _mesh(n):
-    return compat.make_mesh((n,), ("x",), devices=jax.devices()[:n])
+    return Mesh(jax.devices()[:n], ("x",))
 
 
 def _global_input(collective, n, rng):
@@ -106,7 +107,7 @@ def bench_point(n: int, collective: str, repeats: int = 3) -> Dict:
     def fresh_interpreter():
         """One *cold* interpreter call: new jit wrapper, full retrace."""
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 lambda x: prim.run_reference(collective, x[0], sched, "x")[None],
                 mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
                 check_vma=False,
@@ -178,7 +179,7 @@ def bench_fused_matmul_rs(n: int, M: int, K: int, N: int, repeats: int = 5) -> D
     Mc = M // n
     interpret = jax.default_backend() == "cpu"
 
-    mm = jax.jit(compat.shard_map(
+    mm = jax.jit(jax.shard_map(
         lambda xl, wl: matmul_pallas(
             xl[0], wl, block_m=Mc, block_n=N, block_k=K, interpret=interpret
         )[None],
@@ -285,6 +286,7 @@ def main() -> None:
                     help="also write the JSON here (even under --smoke); "
                     "used by the CI bench gate")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ns = (8,) if args.smoke else (8, 16)
     points: List[Dict] = []

@@ -25,17 +25,17 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
-from repro import compat
 from repro.api import PcclSession
 from repro.configs import get_config
 from repro.core import cost_model as cm
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model, unbox
 from repro.models.module import param_count
-from repro.train.optimizer import OptimizerConfig, adamw_update, init_opt_state
+from repro.train.optimizer import OptimizerConfig, init_opt_state
+from repro.train.train_step import make_dp_train_step
 
 
 def main():
@@ -48,9 +48,10 @@ def main():
     ap.add_argument("--backend", default="interp", choices=["interp", "xla"],
                     help="interp = PCCL ppermute schedules; xla = native psum baseline")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = Mesh(jax.devices(), ("data",))
 
     # ~100M params: d=512, 8L, vocab 32k → ≈ 60M; bump ff for ~100M
     cfg = dataclasses.replace(
@@ -74,29 +75,9 @@ def main():
     opt_state = init_opt_state(params)
     data = SyntheticLMData(cfg, DataConfig(global_batch=args.batch, seq_len=args.seq))
 
-    def per_shard_step(params, opt_state, batch):
-        # per-device loss on the local batch shard; grads averaged via the
-        # schedule-driven PCCL all-reduce (ppermute rounds)
-        def loss_fn(p):
-            loss, _ = model.loss(p, batch)
-            return loss
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = jax.tree.map(lambda g: comm.all_reduce(g) / n_dev, grads)
-        loss = jax.lax.psum(loss, "data") / n_dev
-        new_params, new_opt, _ = adamw_update(opt_cfg, grads, params, opt_state)
-        return new_params, new_opt, loss
-
-    step_fn = jax.jit(
-        compat.shard_map(
-            per_shard_step,
-            mesh=mesh,
-            in_specs=(P(), P(), {"tokens": P("data", None)}),
-            out_specs=(P(), P(), P()),
-            check_vma=False,
-        ),
-        donate_argnums=(0, 1),
-    )
+    # per-device loss on the local batch shard; grads averaged via the
+    # schedule-driven PCCL all-reduce (ppermute rounds)
+    step_fn = make_dp_train_step(model, opt_cfg, comm, mesh)
 
     t0 = time.perf_counter()
     for step in range(args.steps):

@@ -115,7 +115,7 @@ def _eager_eligible(x) -> bool:
     """
     import jax
 
-    return not isinstance(x, jax.core.Tracer) and jax.core.trace_state_clean()
+    return not isinstance(x, jax.core.Tracer) and jax.core.trace_ctx.is_top_level()
 
 
 class InterpBackend:
@@ -288,9 +288,9 @@ def _build_executable(comm, collective, sched, global_shape):
     coincide — the same Box model the kernel lint applies to
     ``input_output_aliases``; no tracing, so 0-retrace guarantees hold)."""
     import jax
+    from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.comm import exec_engine
 
     backend = comm.backend  # stateless InterpBackend
@@ -300,11 +300,9 @@ def _build_executable(comm, collective, sched, global_shape):
     def inner(xl):
         return backend._traced(view, collective, xl[0], sched)[None]
 
-    mesh = compat.make_mesh(
-        (view.axis_size,), (axis,), devices=jax.devices()[: view.axis_size]
-    )
+    mesh = Mesh(jax.devices()[: view.axis_size], (axis,))
     spec = P(axis, *([None] * (len(global_shape) - 1)))
-    fun = compat.shard_map(
+    fun = jax.shard_map(
         inner, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )
     donate = (

@@ -374,6 +374,23 @@ def donation_compatible(collective: str, global_shape: Tuple[int, ...]) -> bool:
 # --------------------------------------------------------------- execution
 
 
+_LANE = 128  # TPU vector lane width
+
+
+def _lane_view(chunks):
+    """``(k, m)`` chunk buffer → ``(k, m // _LANE, _LANE)`` where ``m`` allows.
+
+    A flat buffer split into ``k`` chunks is a 2-D array whose
+    second-minor dimension is the small chunk count; the TPU compiler
+    tiles that layout badly, and its compile time grows with ``m`` (about
+    25 s at 200 MB per chunk buffer on v5e).  The 3-D view keeps every
+    element in its chunk and position, so results stay bit-identical.
+    """
+    if chunks.ndim == 2 and chunks.shape[1] > _LANE and chunks.shape[1] % _LANE == 0:
+        return chunks.reshape(chunks.shape[0], chunks.shape[1] // _LANE, _LANE)
+    return chunks
+
+
 def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=None):
     """Run a compiled schedule on a local chunk buffer inside ``shard_map``.
 
@@ -381,13 +398,16 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
     same permutation per round, same scatter-add/store order.  ``me``
     defaults to ``lax.axis_index(axis_name)``; grouped callers that index
     their buffers with a *group-local* rank still pass nothing here — the
-    tables are always row-indexed by the global axis index.
+    tables are always row-indexed by the global axis index.  Flat chunk
+    buffers run in their :func:`_lane_view`.
     """
     import jax.numpy as jnp
     from jax import lax
 
     if me is None:
         me = lax.axis_index(axis_name)
+    shape = chunks.shape
+    chunks = _lane_view(chunks)
 
     def apply_round(buf, send, recv, grp):
         payload = jnp.take(buf, send, axis=0)
@@ -405,7 +425,7 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
                 return apply_round(buf, sr[0], sr[1], _grp), None
 
             chunks, _ = lax.scan(body, chunks, (send, recv))
-    return chunks
+    return chunks.reshape(shape)
 
 
 def execute_all_to_all_compact(blocks, compiled: CompiledSchedule, axis_name: str, me):

@@ -306,9 +306,9 @@ def _build_fused_mm_rs(comm, prog, x_shape, N, dtype, blocks, interpret):
     import jax
     import jax.numpy as jnp
     from jax import lax
+    from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.kernels.matmul.kernel import matmul_pallas
 
     axis = comm.axis_name
@@ -348,8 +348,8 @@ def _build_fused_mm_rs(comm, prog, x_shape, N, dtype, blocks, interpret):
         buf, _ = lax.scan(body, buf, (order[1:], send, recv))
         return jnp.take(buf, me, axis=0)[None]
 
-    mesh = compat.make_mesh((S,), (axis,), devices=jax.devices()[:S])
-    fun = compat.shard_map(
+    mesh = Mesh(jax.devices()[:S], (axis,))
+    fun = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(axis, None, None), P(None, None)),
@@ -437,9 +437,9 @@ def fused_all_reduce_rmsnorm(
 
 def _build_fused_ar_rms(comm, sched, x_shape, eps, interpret):
     import jax
+    from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.comm import primitives as prims
     from repro.kernels.rmsnorm.ops import rmsnorm
 
@@ -454,9 +454,9 @@ def _build_fused_ar_rms(comm, sched, x_shape, eps, interpret):
         out = rmsnorm(red, g, eps=eps, use_pallas=True, interpret=interpret)
         return out[None]
 
-    mesh = compat.make_mesh((S,), (axis,), devices=jax.devices()[:S])
+    mesh = Mesh(jax.devices()[:S], (axis,))
     spec = P(axis, *([None] * (len(x_shape) - 1)))
-    fun = compat.shard_map(
+    fun = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(spec, P(None)),

@@ -10,6 +10,14 @@ wraps to 0.  Per chunk the kernel computes
     Y_off  = exp(cum_t) · (C R)                           (cross-chunk)
     R'     = exp(total) · R + Σ_s exp(total − cum_s) X_s ⊗ B_s
 
+The per-chunk prefix sums ``cum`` of the log-decays are computed by the
+wrapper (one XLA cumsum; Mosaic has no cumsum lowering) and fed to the
+kernel as a lane-dense row (1, L).  The kernel turns the row into the
+column it also needs with a masked lane reduction over the diagonal —
+exact, since every other term is 0.0 — so neither a transpose of a
+vector nor a scalar read is lowered, and HBM holds no (L, 1) column
+padded 128-fold to the lane width.
+
 Tile sizes: chunk length L × head_dim P and L × state N — L defaults to 128
 (MXU-aligned); P/N are the model's head_dim/d_state (128/64 for the assigned
 archs → aligned or half-aligned lanes).
@@ -30,7 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(
     x_ref,      # (L, P)
-    la_ref,     # (L, 1)
+    cum_ref,    # (1, L) per-chunk prefix sums of the log-decays
     b_ref,      # (L, N)
     c_ref,      # (L, N)
     y_ref,      # (L, P)
@@ -44,19 +52,20 @@ def _ssd_kernel(
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (L, P)
-    la = la_ref[...].astype(jnp.float32)        # (L, 1)
+    cum_row = cum_ref[...]                      # (1, L)
     b = b_ref[...].astype(jnp.float32)          # (L, N)
     c = c_ref[...].astype(jnp.float32)          # (L, N)
     L = x.shape[0]
+    total = cum_row[:, L - 1:]                  # (1, 1)
 
-    cum = jnp.cumsum(la, axis=0)                # (L, 1)
-    total = cum[L - 1, 0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    cum_full = jnp.broadcast_to(cum_row, (L, L))
+    cum = jnp.sum(jnp.where(row == col, cum_full, 0.0), axis=1, keepdims=True)  # (L, 1)
 
     # intra-chunk
-    dec = cum - cum.T                           # (L, L): cum_t - cum_s
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= jax.lax.broadcasted_iota(
-        jnp.int32, (L, L), 1
-    )
+    dec = cum - cum_row                         # (L, L): cum_t - cum_s
+    tri = row >= col
     w = jnp.where(tri, jnp.exp(dec), 0.0) * (c @ b.T)
     y = w @ x                                   # (L, P)
 
@@ -104,7 +113,11 @@ def ssd_pallas(
     nc = S // chunk
 
     xb = X.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    lab = la.transpose(0, 2, 1).reshape(B * H, S, 1)
+    cum = jnp.cumsum(
+        la.astype(jnp.float32).transpose(0, 2, 1).reshape(B * H, nc, chunk),
+        axis=-1,
+    )
+    cum = cum.reshape(B * H, nc, 1, chunk)
     bb = Bm.transpose(0, 2, 1, 3).reshape(B * H, S, N)
     cb = Cm.transpose(0, 2, 1, 3).reshape(B * H, S, N)
 
@@ -114,7 +127,7 @@ def ssd_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, chunk, P), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((None, chunk, 1), lambda h, c: (h, c, 0)),
+            pl.BlockSpec((None, None, 1, chunk), lambda h, c: (h, c, 0, 0)),
             pl.BlockSpec((None, chunk, N), lambda h, c: (h, c, 0)),
             pl.BlockSpec((None, chunk, N), lambda h, c: (h, c, 0)),
         ],
@@ -128,7 +141,7 @@ def ssd_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xb, lab, bb, cb)
+    )(xb, cum, bb, cb)
     Y = y.reshape(B, H, S, P).transpose(0, 2, 1, 3)[:, :orig_S]
     final = fin.reshape(B, H, P, N)
     return Y, final

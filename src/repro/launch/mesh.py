@@ -7,18 +7,15 @@ adds a leading "pod" axis: 2×16×16 = 512 chips.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
 
 import jax
-
-from repro import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    import math
-
     n = math.prod(shape)
     devices = jax.devices()[:n]  # dry-run forces 512 host devices; 1 pod uses 256
     if len(devices) < n:
@@ -26,14 +23,4 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices, have {len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py sets this)"
         )
-    return compat.make_mesh(shape, axes, devices=devices)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return compat.make_mesh(shape, axes)
-
-
-def make_host_mesh(n: Optional[int] = None, axis: str = "data"):
-    """Small all-devices mesh for tests/examples on host devices."""
-    n = n or len(jax.devices())
-    return make_mesh((n,), (axis,))
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes), devices=devices)

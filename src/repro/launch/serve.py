@@ -1,6 +1,7 @@
-"""Serving CLI: ``python -m repro.launch.serve --arch <id> --reduced``
+"""Serving CLI: ``python -m repro.launch.serve --arch <id> [--reduced]``
 
-Runs batched prefill + decode on a reduced config and reports tokens/s.
+Runs batched prefill + decode on the config (at its published widths, or
+its tiny ``reduced()`` variant) and reports tokens/s.
 """
 
 from __future__ import annotations
@@ -11,17 +12,19 @@ import time
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

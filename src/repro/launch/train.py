@@ -17,6 +17,7 @@ import argparse
 from repro.ckpt.checkpoint import CheckpointConfig
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.cache import enable_compile_cache
 from repro.runtime.fault import FailureInjector
 from repro.train.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

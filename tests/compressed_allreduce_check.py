@@ -15,9 +15,9 @@ os.environ["XLA_FLAGS"] = (
 import jax
 import numpy as np
 from jax import lax
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.comm.pccl_collectives import (
     ErrorFeedbackState,
     compressed_all_reduce,
@@ -28,12 +28,12 @@ N = 4
 
 
 def _mesh():
-    return compat.make_mesh((N,), ("x",))
+    return Mesh(jax.devices()[:N], ("x",))
 
 
 def _smap(f, mesh, in_specs, out_specs):
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
     )

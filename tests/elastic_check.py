@@ -11,14 +11,13 @@ os.environ["XLA_FLAGS"] = (
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.runtime.fault import reshard_tree, shrink_mesh
 
 
 def main():
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
     sh = {
         "w": NamedSharding(mesh, P("data", "model")),
         "b": NamedSharding(mesh, P(None, "model")),

@@ -25,9 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.api import PcclSession, subgroup_schedule
 from repro.comm import exec_engine
 from repro.comm import primitives as prim
@@ -43,12 +43,12 @@ ALGOS = {
 
 
 def mesh_of(n):
-    return compat.make_mesh((n,), ("x",), devices=jax.devices()[:n])
+    return Mesh(jax.devices()[:n], ("x",))
 
 
 def smap(f, mesh, in_specs, out_specs):
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
     )

@@ -18,9 +18,9 @@ import warnings
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.api import PcclSession
 from repro.comm import primitives as prim
 from repro.comm.pccl_collectives import (
@@ -38,11 +38,11 @@ N = 8
 
 
 def _mesh():
-    return compat.make_mesh((N,), ("x",))
+    return Mesh(jax.devices()[:N], ("x",))
 
 
 def _smap(f, mesh, in_specs, out_specs):
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
 def check_reduce_scatter():
