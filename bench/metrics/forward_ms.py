@@ -1,0 +1,9 @@
+"""forward_ms: device milliseconds per step under the program's ``train_forward``
+scope and not its transpose (bench/scopes.py), averaged over the cell's
+devices.  Nothing to read where the trace names no such op."""
+
+from bench import scopes
+
+
+def read(trace, run):
+    return scopes.ms_per_step(trace, run, "forward")

@@ -35,8 +35,7 @@ Writes ``BENCH_exec.json``::
                 {n, collective: "fused_matmul_reduce_scatter"|
                     "fused_all_reduce_rmsnorm",
                  algorithm, shape, mode: "fused",
-                 seq_warm_s, fused_warm_s, speedup, overlap_fraction,
-                 chunks_streamed, bytes_hidden}, ...],
+                 seq_warm_s, fused_warm_s, speedup, overlap_fraction}, ...],
      "smoke": bool}
 
 Fused rows carry ``mode: "fused"`` and a ``shape`` string so the bench
@@ -211,7 +210,6 @@ def bench_fused_matmul_rs(n: int, M: int, K: int, N: int, repeats: int = 5) -> D
         t0 = time.perf_counter()
         sequential()
         seq_warm_s = min(seq_warm_s, time.perf_counter() - t0)
-    s2 = exec_engine.exec_stats()
     return {
         "n": n,
         "collective": "fused_matmul_reduce_scatter",
@@ -222,10 +220,6 @@ def bench_fused_matmul_rs(n: int, M: int, K: int, N: int, repeats: int = 5) -> D
         "fused_warm_s": fused_warm_s,
         "speedup": seq_warm_s / fused_warm_s if fused_warm_s > 0 else float("inf"),
         "overlap_fraction": max(0.0, 1.0 - fused_warm_s / seq_warm_s),
-        "chunks_streamed": (s2.chunks_streamed - s0.chunks_streamed)
-        // max(1, s2.fused_dispatches - s0.fused_dispatches),
-        "bytes_hidden": (s2.bytes_hidden - s0.bytes_hidden)
-        // max(1, s2.fused_dispatches - s0.fused_dispatches),
     }
 
 
@@ -272,8 +266,6 @@ def bench_fused_ar_rmsnorm(n: int, rows: int, d: int, repeats: int = 5) -> Dict:
         "fused_warm_s": fused_warm_s,
         "speedup": seq_warm_s / fused_warm_s if fused_warm_s > 0 else float("inf"),
         "overlap_fraction": max(0.0, 1.0 - fused_warm_s / seq_warm_s),
-        "chunks_streamed": 0,
-        "bytes_hidden": 0,
     }
 
 

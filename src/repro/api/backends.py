@@ -149,30 +149,30 @@ class InterpBackend:
 
     def _traced(self, comm, collective, x, sched: "Optional[Schedule]"):
         """Trace-time body; ``sched`` pre-resolved on the eager path (the
-        executable must run exactly the schedule its cache key names)."""
+        executable must run exactly the schedule its cache key names).
+        Every op it adds, chunking and padding included, is named
+        ``pccl_<collective>_<algorithm>`` (``exec_engine.collective_scope``)."""
         from repro.comm import exec_engine
 
         exec_engine.note_trace()
-        if collective == "all_reduce":
+        pad = (-x.size) % comm.n if collective == "all_reduce" else 0
+        if sched is None:
+            mult = comm.n if collective == "all_gather" else 1
+            sched = comm.axis_schedule(
+                collective, (x.size + pad) * _item_bytes(x) * mult
+            )
+        with exec_engine.collective_scope(sched.collective, sched.algorithm):
+            if collective != "all_reduce":
+                return self._run(comm, collective, x, sched)
             import jax.numpy as jnp
 
-            shape = x.shape
             flat = x.reshape(-1)
-            pad = (-flat.size) % comm.n
             if pad:
                 flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-            if sched is None:
-                sched = comm.axis_schedule(
-                    "all_reduce", flat.size * _item_bytes(flat)
-                )
             out = self._run(comm, "all_reduce", flat, sched)
             if pad:
                 out = out[: out.size - pad]
-            return out.reshape(shape)
-        if sched is None:
-            mult = comm.n if collective == "all_gather" else 1
-            sched = comm.axis_schedule(collective, x.size * _item_bytes(x) * mult)
-        return self._run(comm, collective, x, sched)
+            return out.reshape(x.shape)
 
     # -- dispatch: ungrouped → primitives; grouped → local-rank variants --
     def _run(self, comm, collective, x, sched):

@@ -27,7 +27,11 @@ Execution (:func:`execute_compiled`) is **bit-identical** to the reference
 interpreter: the same integer chunk ids are gathered, permuted and
 scattered in the same order, so reductions see the same add order per
 receiver.  The ``lax.scan`` merely rolls the identical round body into a
-loop.
+loop.  Every device op it emits is named in the HLO ``op_name`` (and so in
+a profiler trace) ``pccl_<collective>_<algorithm>/group<i>/...``, with
+``group<i>`` the round group; :func:`collective_scope` opens the outer
+name, and ``repro.api.backends`` opens it around a collective's chunking
+and padding too.
 
 Caches and counters (compiled-table LRU, the jitted-executable LRU that
 ``repro.api.backends`` fills, and the trace counter) are process-wide,
@@ -38,7 +42,9 @@ and sim-only processes can read stats without touching it.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import re
 import sys
 import threading
 from collections import OrderedDict
@@ -56,6 +62,7 @@ __all__ = [
     "ExecStats",
     "RoundGroup",
     "clear_exec_caches",
+    "collective_scope",
     "compile_all_to_all",
     "compile_schedule",
     "donation_compatible",
@@ -391,6 +398,32 @@ def _lane_view(chunks):
     return chunks
 
 
+_SCOPE = threading.local()  # the collective scope open on this thread's trace
+
+
+@contextlib.contextmanager
+def collective_scope(collective: str, algorithm: str):
+    """Name the device ops traced inside ``pccl_<collective>_<algorithm>``.
+
+    A ``jax.named_scope``: it sets HLO ``op_name`` metadata and nothing
+    else, so it costs nothing at run time.  Opened again inside itself (the
+    backend around the engine) it adds no second level.
+    """
+    import jax
+
+    name = re.sub(r"\W", "_", f"pccl_{collective}_{algorithm}")
+    outer = getattr(_SCOPE, "name", None)
+    if outer == name:
+        yield
+        return
+    _SCOPE.name = name
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        _SCOPE.name = outer
+
+
 def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=None):
     """Run a compiled schedule on a local chunk buffer inside ``shard_map``.
 
@@ -399,33 +432,37 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
     defaults to ``lax.axis_index(axis_name)``; grouped callers that index
     their buffers with a *group-local* rank still pass nothing here — the
     tables are always row-indexed by the global axis index.  Flat chunk
-    buffers run in their :func:`_lane_view`.
+    buffers run in their :func:`_lane_view`.  The ops of round group ``i``
+    are named ``pccl_<collective>_<algorithm>/group<i>``.
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
     if me is None:
         me = lax.axis_index(axis_name)
     shape = chunks.shape
-    chunks = _lane_view(chunks)
 
     def apply_round(buf, send, recv, grp):
         payload = jnp.take(buf, send, axis=0)
         got = lax.ppermute(payload, axis_name, grp.perm)
         return buf.at[recv].add(got) if grp.reduce else buf.at[recv].set(got)
 
-    for grp in compiled.groups:
-        send = jnp.take(jnp.asarray(grp.send_ids), me, axis=1)  # (rounds, k)
-        recv = jnp.take(jnp.asarray(grp.recv_ids), me, axis=1)
-        if grp.rounds == 1:
-            chunks = apply_round(chunks, send[0], recv[0], grp)
-        else:
+    with collective_scope(compiled.collective, compiled.algorithm):
+        chunks = _lane_view(chunks)
+        for i, grp in enumerate(compiled.groups):
+            with jax.named_scope(f"group{i}"):
+                send = jnp.take(jnp.asarray(grp.send_ids), me, axis=1)  # (rounds, k)
+                recv = jnp.take(jnp.asarray(grp.recv_ids), me, axis=1)
+                if grp.rounds == 1:
+                    chunks = apply_round(chunks, send[0], recv[0], grp)
+                else:
 
-            def body(buf, sr, _grp=grp):
-                return apply_round(buf, sr[0], sr[1], _grp), None
+                    def body(buf, sr, _grp=grp):
+                        return apply_round(buf, sr[0], sr[1], _grp), None
 
-            chunks, _ = lax.scan(body, chunks, (send, recv))
-    return chunks.reshape(shape)
+                    chunks, _ = lax.scan(body, chunks, (send, recv))
+        return chunks.reshape(shape)
 
 
 def execute_all_to_all_compact(blocks, compiled: CompiledSchedule, axis_name: str, me):
@@ -492,15 +529,13 @@ EXECUTABLES = _LruCache(max_entries=128)  # exec key → jitted callable
 _TRACE_LOCK = threading.Lock()
 _TRACES = 0
 
-# Overlap counters, filled by repro.comm.fusion: dispatches that streamed
-# producer tiles into collective rounds (vs. took the sequential fallback),
-# how many chunks were streamed, and how many payload bytes moved while
-# compute was still running (an upper bound on hideable wire time).
+# Dispatch counters, filled by repro.comm.fusion: dispatches that streamed
+# producer tiles into collective rounds vs. took the sequential fallback.
+# What communication a dispatch hides is device time, which only a
+# profiler trace measures; these count dispatches.
 _OVERLAP_LOCK = threading.Lock()
 _FUSED_DISPATCHES = 0
 _FALLBACK_DISPATCHES = 0
-_CHUNKS_STREAMED = 0
-_BYTES_HIDDEN = 0
 
 
 def note_trace() -> None:
@@ -510,13 +545,11 @@ def note_trace() -> None:
         _TRACES += 1
 
 
-def note_fused_dispatch(chunks_streamed: int, bytes_hidden: int) -> None:
-    """Record one fused (comm-under-compute) dispatch and its overlap volume."""
-    global _FUSED_DISPATCHES, _CHUNKS_STREAMED, _BYTES_HIDDEN
+def note_fused_dispatch() -> None:
+    """Record one fused (comm-under-compute) dispatch."""
+    global _FUSED_DISPATCHES
     with _OVERLAP_LOCK:
         _FUSED_DISPATCHES += 1
-        _CHUNKS_STREAMED += int(chunks_streamed)
-        _BYTES_HIDDEN += int(bytes_hidden)
 
 
 def note_fallback_dispatch() -> None:
@@ -539,8 +572,6 @@ class ExecStats:
     traces: int
     fused_dispatches: int = 0
     fallback_dispatches: int = 0
-    chunks_streamed: int = 0
-    bytes_hidden: int = 0
 
 
 def exec_stats() -> ExecStats:
@@ -552,15 +583,13 @@ def exec_stats() -> ExecStats:
     * ``compiled_*`` — the schedule→stacked-tables compile cache.
     * ``traces`` — how many times a Python trace actually ran; a warm
       steady state stops incrementing it.
-    * ``fused_*``/``fallback_*``/``chunks_streamed``/``bytes_hidden`` —
-      overlap counters from ``repro.comm.fusion`` (see
-      :func:`note_fused_dispatch`).
+    * ``fused_*``/``fallback_*`` — dispatch counters from
+      ``repro.comm.fusion`` (see :func:`note_fused_dispatch`).
     """
     with _TRACE_LOCK:
         traces = _TRACES
     with _OVERLAP_LOCK:
         fused, fallback = _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
-        streamed, hidden = _CHUNKS_STREAMED, _BYTES_HIDDEN
     return ExecStats(
         executable_hits=EXECUTABLES.hits,
         executable_misses=EXECUTABLES.misses,
@@ -571,8 +600,6 @@ def exec_stats() -> ExecStats:
         traces=traces,
         fused_dispatches=fused,
         fallback_dispatches=fallback,
-        chunks_streamed=streamed,
-        bytes_hidden=hidden,
     )
 
 
@@ -586,14 +613,12 @@ def clear_exec_caches() -> None:
     processes that this module deliberately keeps JAX-free.
     """
     global _TRACES, _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
-    global _CHUNKS_STREAMED, _BYTES_HIDDEN
     _COMPILED.clear()
     EXECUTABLES.clear()
     with _TRACE_LOCK:
         _TRACES = 0
     with _OVERLAP_LOCK:
         _FUSED_DISPATCHES = _FALLBACK_DISPATCHES = 0
-        _CHUNKS_STREAMED = _BYTES_HIDDEN = 0
     lint = sys.modules.get("repro.analysis.kernel_lint")
     if lint is not None:
         lint.clear_verified_cache()

@@ -273,16 +273,7 @@ def fused_matmul_reduce_scatter(
         )
         exec_engine.EXECUTABLES.put(key, fn)
     out = fn(x, w)
-    Mc = M // n
-    # every round but the last runs with later tiles still pending
-    exec_engine.note_fused_dispatch(
-        chunks_streamed=n,
-        bytes_hidden=comm.axis_size
-        * max(0, prog.rounds - 1)
-        * Mc
-        * N
-        * x.dtype.itemsize,
-    )
+    exec_engine.note_fused_dispatch()
     return out
 
 
@@ -429,9 +420,7 @@ def fused_all_reduce_rmsnorm(
         fn = _build_fused_ar_rms(comm, sched, tuple(x.shape), eps, interpret)
         exec_engine.EXECUTABLES.put(key, fn)
     out = fn(x, gamma)
-    # consumer-side fusion: no producer tiles streamed, but one whole
-    # normalization pass (read + write of the local buffer) is hidden
-    exec_engine.note_fused_dispatch(chunks_streamed=0, bytes_hidden=0)
+    exec_engine.note_fused_dispatch()
     return out
 
 

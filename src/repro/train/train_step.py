@@ -4,6 +4,14 @@ dry-run lowers.
 ``make_train_step`` supports microbatch gradient accumulation (sequential
 ``lax.scan`` over microbatches — the standard memory/throughput trade) and
 donates params+opt_state so the update is in-place at the XLA level.
+
+Both training steps name their phases with ``jax.named_scope``, which sets
+the HLO ``op_name`` of every operation and so names it in a profiler trace:
+``train_forward`` (the loss; the backward pass is what AD derives from it,
+``transpose(jvp(train_forward))``, and the remat recompute sits under that
+transpose), ``train_optimizer`` (the AdamW update) and, in the
+data-parallel step, ``train_grad_sync`` (the gradient all-reduce and the
+loss mean).  A scope changes no instruction, only metadata.
 """
 
 from __future__ import annotations
@@ -28,7 +36,8 @@ def make_train_step(
 
     def grads_of(params, batch):
         def loss_fn(p):
-            loss, metrics = model.loss(p, batch)
+            with jax.named_scope("train_forward"):
+                loss, metrics = model.loss(p, batch)
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -55,7 +64,8 @@ def make_train_step(
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             metrics = {"xent": loss}
 
-        new_params, new_opt, opt_metrics = adamw_update(opt_cfg, grads, params, opt_state)
+        with jax.named_scope("train_optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(opt_cfg, grads, params, opt_state)
         out = {"loss": loss, **{k: v for k, v in metrics.items()}, **opt_metrics}
         return new_params, new_opt, out
 
@@ -79,13 +89,16 @@ def make_dp_train_step(model: Model, opt_cfg: OptimizerConfig, comm, mesh) -> Ca
 
     def per_shard_step(params, opt_state, batch):
         def loss_fn(p):
-            loss, _ = model.loss(p, batch)
+            with jax.named_scope("train_forward"):
+                loss, _ = model.loss(p, batch)
             return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = jax.tree.map(lambda g: comm.all_reduce(g) / n, grads)
-        loss = jax.lax.psum(loss, axis) / n
-        new_params, new_opt, _ = adamw_update(opt_cfg, grads, params, opt_state)
+        with jax.named_scope("train_grad_sync"):
+            grads = jax.tree.map(lambda g: comm.all_reduce(g) / n, grads)
+            loss = jax.lax.psum(loss, axis) / n
+        with jax.named_scope("train_optimizer"):
+            new_params, new_opt, _ = adamw_update(opt_cfg, grads, params, opt_state)
         return new_params, new_opt, loss
 
     return jax.jit(
