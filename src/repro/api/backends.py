@@ -125,12 +125,32 @@ class InterpBackend:
     are memoized process-wide, so retraces skip all Python table
     derivation).  Called with concrete arrays, the backend instead routes
     through :func:`_run_eager`'s jitted-executable cache.
+
+    ``all_reduce`` also takes a pytree of arrays (a gradient tree).  Each
+    leaf is padded and chunked as alone, then the leaves are grouped by
+    the fingerprint of their compiled schedule, which ignores byte sizes,
+    and each group runs through one round loop shared by all its leaves
+    (``exec_engine.execute_compiled`` given several buffers, counted in
+    ``exec_stats().shared_loop_buffers``): one leaf's gathers and adds can
+    then run under another's ``ppermute``.  Every leaf's result is
+    bit-identical to its own ``all_reduce``.  A lone array, a split
+    communicator and ``ring_ef8`` leaves take the one-array path; concrete
+    leaves outside a trace run eagerly one by one.
     """
 
     name = "interp"
 
     def all_reduce(self, comm, x):
-        return self._collective(comm, "all_reduce", x)
+        import jax
+
+        leaves, treedef = jax.tree.flatten(x)
+        if jax.tree_util.treedef_is_leaf(treedef):
+            return self._collective(comm, "all_reduce", x)
+        if all(_eager_eligible(leaf) for leaf in leaves):
+            out = [_run_eager(comm, "all_reduce", leaf) for leaf in leaves]
+        else:
+            out = self._traced_all_reduce_tree(comm, leaves)
+        return treedef.unflatten(out)
 
     def reduce_scatter(self, comm, x):
         return self._collective(comm, "reduce_scatter", x)
@@ -149,30 +169,45 @@ class InterpBackend:
 
     def _traced(self, comm, collective, x, sched: "Optional[Schedule]"):
         """Trace-time body; ``sched`` pre-resolved on the eager path (the
-        executable must run exactly the schedule its cache key names).
+        executable must run exactly the schedule its cache key names) and
+        the tree path.
         Every op it adds, chunking and padding included, is named
         ``pccl_<collective>_<algorithm>`` (``exec_engine.collective_scope``)."""
         from repro.comm import exec_engine
 
         exec_engine.note_trace()
-        pad = (-x.size) % comm.n if collective == "all_reduce" else 0
         if sched is None:
-            mult = comm.n if collective == "all_gather" else 1
-            sched = comm.axis_schedule(
-                collective, (x.size + pad) * _item_bytes(x) * mult
-            )
+            sched = _traced_schedule(comm, collective, x)
         with exec_engine.collective_scope(sched.collective, sched.algorithm):
             if collective != "all_reduce":
                 return self._run(comm, collective, x, sched)
-            import jax.numpy as jnp
+            out = self._run(comm, "all_reduce", _padded_flat(comm, x), sched)
+            return _unpadded(out, x)
 
-            flat = x.reshape(-1)
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-            out = self._run(comm, "all_reduce", flat, sched)
-            if pad:
-                out = out[: out.size - pad]
-            return out.reshape(x.shape)
+    def _traced_all_reduce_tree(self, comm, leaves):
+        """Trace-time all-reduce of many leaves: those whose compiled
+        schedules share a fingerprint run through one round loop."""
+        from repro.comm import exec_engine
+
+        out = list(leaves)
+        shared = {}  # schedule fingerprint -> (schedule, leaf indices)
+        for i, x in enumerate(leaves):
+            sched = _traced_schedule(comm, "all_reduce", x)
+            if comm.groups is None and sched.algorithm != "ring_ef8":
+                shared.setdefault(sched.fingerprint(), (sched, []))[1].append(i)
+            else:
+                out[i] = self._traced(comm, "all_reduce", x, sched)
+        for sched, idx in shared.values():
+            for _ in idx:
+                exec_engine.note_trace()
+            with exec_engine.collective_scope(sched.collective, sched.algorithm):
+                chunks = [_padded_flat(comm, leaves[i]).reshape(comm.n, -1) for i in idx]
+                chunks = exec_engine.execute_compiled(
+                    chunks, exec_engine.compile_schedule(sched), comm.axis_name
+                )
+                for i, c in zip(idx, chunks):
+                    out[i] = _unpadded(c.reshape(-1), leaves[i])
+        return out
 
     # -- dispatch: ungrouped → primitives; grouped → local-rank variants --
     def _run(self, comm, collective, x, sched):
@@ -188,11 +223,37 @@ class InterpBackend:
         return _grouped_collective(comm, collective, x, sched)
 
 
+def _traced_schedule(comm, collective, x):
+    """The schedule the trace path runs for local operand ``x``."""
+    return comm.axis_schedule(
+        collective, _local_nbytes(comm, collective, x.shape, _item_bytes(x))
+    )
+
+
+def _padded_flat(comm, x):
+    """``x`` flattened and zero-padded to a multiple of ``comm.n``."""
+    flat = x.reshape(-1)
+    pad = (-flat.size) % comm.n
+    if pad:
+        import jax.numpy as jnp
+
+        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+    return flat
+
+
+def _unpadded(flat, x):
+    """The all-reduced ``flat`` cut back to ``x``'s size and shape."""
+    if flat.size != x.size:
+        flat = flat[: x.size]
+    return flat.reshape(x.shape)
+
+
 # ------------------------------------------------------------- eager path
 
 
-def _eager_nbytes(comm, collective, local_shape, itemsize: int) -> float:
-    """The nbytes the trace path will derive from the local operand."""
+def _local_nbytes(comm, collective, local_shape, itemsize: int) -> float:
+    """The nbytes a collective of a local operand is planned for (an
+    all-reduce operand padded to a multiple of ``comm.n``)."""
     import math
 
     size = math.prod(local_shape) if local_shape else 1
@@ -236,7 +297,7 @@ def _run_eager(comm, collective, x):
             "inside shard_map or set --xla_force_host_platform_device_count"
         )
     sched = comm.axis_schedule(
-        collective, _eager_nbytes(comm, collective, x.shape[1:], _item_bytes(x))
+        collective, _local_nbytes(comm, collective, x.shape[1:], _item_bytes(x))
     )
     key = (
         sched.fingerprint(),
@@ -412,7 +473,16 @@ class SimBackend:
         self.events.append((collective, float(nbytes), cost))
 
     def all_reduce(self, comm, x):
-        self._charge(comm, "all_reduce", x.size * _item_bytes(x))
+        """``x`` an array or a pytree of arrays: each leaf is charged as
+        its own all-reduce."""
+        if hasattr(x, "dtype"):
+            leaves = [x]
+        else:
+            import jax
+
+            leaves = jax.tree.leaves(x)
+        for leaf in leaves:
+            self._charge(comm, "all_reduce", leaf.size * _item_bytes(leaf))
         return x
 
     def reduce_scatter(self, comm, x):

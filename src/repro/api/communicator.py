@@ -160,6 +160,11 @@ class Communicator:
 
     # ----------------------------------------------------------- primitives
     def all_reduce(self, x):
+        """x: per-rank addend, or a pytree of them → the sum over ranks.
+
+        A pytree is reduced leaf by leaf with the same results as one call
+        per leaf; the ``interp`` backend runs leaves that share a compiled
+        schedule through one round loop (see ``InterpBackend``)."""
         return self.backend.all_reduce(self, x)
 
     def reduce_scatter(self, x):

@@ -27,14 +27,22 @@ Execution (:func:`execute_compiled`) is **bit-identical** to the reference
 interpreter: the same integer chunk ids are gathered, permuted and
 scattered in the same order, so reductions see the same add order per
 receiver.  The ``lax.scan`` merely rolls the identical round body into a
-loop.  Every device op it emits is named in the HLO ``op_name`` (and so in
-a profiler trace) ``pccl_<collective>_<algorithm>/group<i>/...``, with
-``group<i>`` the round group; :func:`collective_scope` opens the outer
+loop.  Several buffers that run one compiled schedule (the leaves of a
+gradient tree, from ``repro.api.backends``) can share that loop: each round
+group runs once, and its body is a software pipeline over the buffers,
+largest first — one ``ppermute`` in flight at a time, the next buffer's
+gather and the last buffer's add running under it.  Each buffer still
+sees exactly the rounds it would alone, and one buffer emits the same ops
+as before.  Every device op it emits is named in the HLO
+``op_name`` (and so in a profiler trace)
+``pccl_<collective>_<algorithm>/group<i>/...``, with ``group<i>`` the
+round group; :func:`collective_scope` opens the outer
 name, and ``repro.api.backends`` opens it around a collective's chunking
 and padding too.
 
 Caches and counters (compiled-table LRU, the jitted-executable LRU that
-``repro.api.backends`` fills, and the trace counter) are process-wide,
+``repro.api.backends`` fills, the trace counter and the count of buffers
+traced through a shared round loop) are process-wide,
 lock-guarded and surfaced through :func:`exec_stats` /
 ``PcclSession.exec_stats()``.  This module imports JAX lazily — planning-
 and sim-only processes can read stats without touching it.
@@ -72,6 +80,7 @@ __all__ = [
     "execute_compiled",
     "note_fallback_dispatch",
     "note_fused_dispatch",
+    "note_shared_loop",
     "note_trace",
     "round_tables",
 ]
@@ -425,7 +434,18 @@ def collective_scope(collective: str, algorithm: str):
 
 
 def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=None):
-    """Run a compiled schedule on a local chunk buffer inside ``shard_map``.
+    """Run a compiled schedule on local chunk buffers inside ``shard_map``.
+
+    ``chunks`` is one buffer, or a list or tuple of buffers that all run
+    ``compiled``; the result has the same form.  Several buffers share one
+    round loop: each round group runs once, and its body gathers, permutes
+    and adds or sets every buffer, largest first.  An
+    ``optimization_barrier`` holds each ``ppermute`` until the previous
+    buffer's has landed, so one transfer is in flight at a time and the
+    previous buffer's add runs under it.  (Left free, the TPU scheduler
+    issues many transfers at once and blocks on a small one's done queued
+    behind large ones.)  Each buffer still sees the same chunk ids,
+    permutation and add order as alone.
 
     Bit-identical to the per-round reference interpreter: same gathers,
     same permutation per round, same scatter-add/store order.  ``me``
@@ -439,30 +459,51 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
     import jax.numpy as jnp
     from jax import lax
 
+    many = isinstance(chunks, (list, tuple))
+    bufs = tuple(chunks) if many else (chunks,)
+    if len(bufs) > 1:
+        note_shared_loop(len(bufs))
     if me is None:
         me = lax.axis_index(axis_name)
-    shape = chunks.shape
+    shapes = [b.shape for b in bufs]
 
-    def apply_round(buf, send, recv, grp):
-        payload = jnp.take(buf, send, axis=0)
-        got = lax.ppermute(payload, axis_name, grp.perm)
-        return buf.at[recv].add(got) if grp.reduce else buf.at[recv].set(got)
+    # largest first: each transfer then lasts at least as long as the next
+    # buffer's gather, and the previous buffer's add hides under it unless
+    # that buffer is several times larger
+    order = sorted(range(len(bufs)), key=lambda j: -bufs[j].size)
+
+    def apply_round(bufs, send, recv, grp):
+        def land(buf, got):
+            return buf.at[recv].add(got) if grp.reduce else buf.at[recv].set(got)
+
+        out, prev, got = list(bufs), None, None
+        for j in order:
+            payload = jnp.take(bufs[j], send, axis=0)
+            if prev is not None:
+                # one transfer in flight: this one starts when the last is
+                # done, and the last one's add runs under it
+                got, payload = lax.optimization_barrier((got, payload))
+                out[prev] = land(bufs[prev], got)
+            got, prev = lax.ppermute(payload, axis_name, grp.perm), j
+        out[prev] = land(bufs[prev], got)
+        return tuple(out)
 
     with collective_scope(compiled.collective, compiled.algorithm):
-        chunks = _lane_view(chunks)
+        bufs = tuple(_lane_view(b) for b in bufs)
         for i, grp in enumerate(compiled.groups):
             with jax.named_scope(f"group{i}"):
                 send = jnp.take(jnp.asarray(grp.send_ids), me, axis=1)  # (rounds, k)
                 recv = jnp.take(jnp.asarray(grp.recv_ids), me, axis=1)
                 if grp.rounds == 1:
-                    chunks = apply_round(chunks, send[0], recv[0], grp)
+                    bufs = apply_round(bufs, send[0], recv[0], grp)
                 else:
 
-                    def body(buf, sr, _grp=grp):
-                        return apply_round(buf, sr[0], sr[1], _grp), None
+                    def body(carry, sr, _grp=grp):
+                        return apply_round(carry, sr[0], sr[1], _grp), None
 
-                    chunks, _ = lax.scan(body, chunks, (send, recv))
-        return chunks.reshape(shape)
+                    bufs, _ = lax.scan(body, bufs, (send, recv))
+        out = [b.reshape(shape) for b, shape in zip(bufs, shapes)]
+        return type(chunks)(out) if many else out[0]
 
 
 def execute_all_to_all_compact(blocks, compiled: CompiledSchedule, axis_name: str, me):
@@ -528,6 +569,7 @@ EXECUTABLES = _LruCache(max_entries=128)  # exec key → jitted callable
 
 _TRACE_LOCK = threading.Lock()
 _TRACES = 0
+_SHARED_LOOP_BUFFERS = 0
 
 # Dispatch counters, filled by repro.comm.fusion: dispatches that streamed
 # producer tiles into collective rounds vs. took the sequential fallback.
@@ -543,6 +585,13 @@ def note_trace() -> None:
     global _TRACES
     with _TRACE_LOCK:
         _TRACES += 1
+
+
+def note_shared_loop(buffers: int) -> None:
+    """Record ``buffers`` buffers traced through one shared round loop."""
+    global _SHARED_LOOP_BUFFERS
+    with _TRACE_LOCK:
+        _SHARED_LOOP_BUFFERS += buffers
 
 
 def note_fused_dispatch() -> None:
@@ -572,6 +621,7 @@ class ExecStats:
     traces: int
     fused_dispatches: int = 0
     fallback_dispatches: int = 0
+    shared_loop_buffers: int = 0
 
 
 def exec_stats() -> ExecStats:
@@ -583,11 +633,14 @@ def exec_stats() -> ExecStats:
     * ``compiled_*`` — the schedule→stacked-tables compile cache.
     * ``traces`` — how many times a Python trace actually ran; a warm
       steady state stops incrementing it.
+    * ``shared_loop_buffers`` — buffers traced through a round loop shared
+      with at least one other buffer (:func:`execute_compiled` given
+      several); counted at trace time, like ``traces``.
     * ``fused_*``/``fallback_*`` — dispatch counters from
       ``repro.comm.fusion`` (see :func:`note_fused_dispatch`).
     """
     with _TRACE_LOCK:
-        traces = _TRACES
+        traces, shared = _TRACES, _SHARED_LOOP_BUFFERS
     with _OVERLAP_LOCK:
         fused, fallback = _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
     return ExecStats(
@@ -600,6 +653,7 @@ def exec_stats() -> ExecStats:
         traces=traces,
         fused_dispatches=fused,
         fallback_dispatches=fallback,
+        shared_loop_buffers=shared,
     )
 
 
@@ -612,11 +666,11 @@ def clear_exec_caches() -> None:
     loaded: importing it here would pull JAX into planning-/sim-only
     processes that this module deliberately keeps JAX-free.
     """
-    global _TRACES, _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
+    global _TRACES, _SHARED_LOOP_BUFFERS, _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
     _COMPILED.clear()
     EXECUTABLES.clear()
     with _TRACE_LOCK:
-        _TRACES = 0
+        _TRACES = _SHARED_LOOP_BUFFERS = 0
     with _OVERLAP_LOCK:
         _FUSED_DISPATCHES = _FALLBACK_DISPATCHES = 0
     lint = sys.modules.get("repro.analysis.kernel_lint")

@@ -77,15 +77,25 @@ def make_dp_train_step(model: Model, opt_cfg: OptimizerConfig, comm, mesh) -> Ca
 
     Params and optimizer state are replicated on every device of ``mesh``;
     the batch is split over the communicator's axis.  Each device takes the
-    loss and gradients of its own shard, and ``comm.all_reduce`` averages
-    the gradients: PCCL's planned ppermute rounds on the ``interp``
-    backend, the native ``psum`` on ``xla``.  Returns the jitted
+    loss and gradients of its own shard, and one ``comm.all_reduce`` of the
+    whole gradient tree averages them: PCCL's planned ppermute rounds on
+    the ``interp`` backend, with the leaves sharing round loops, the native
+    ``psum`` on ``xla``.  A ``comm`` that is not a
+    :class:`~repro.api.Communicator` (a stand-in whose ``all_reduce`` takes
+    one array) is called once per leaf.  Returns the jitted
     ``step(params, opt_state, batch) -> (params, opt_state, loss)``, with
     params and optimizer state donated.
     """
     from jax.sharding import PartitionSpec as P
 
+    from repro.api.communicator import Communicator
+
     axis, n = comm.axis_name, comm.n
+    if isinstance(comm, Communicator):
+        all_reduce = comm.all_reduce
+    else:
+        def all_reduce(tree):
+            return jax.tree.map(comm.all_reduce, tree)
 
     def per_shard_step(params, opt_state, batch):
         def loss_fn(p):
@@ -95,7 +105,7 @@ def make_dp_train_step(model: Model, opt_cfg: OptimizerConfig, comm, mesh) -> Ca
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         with jax.named_scope("train_grad_sync"):
-            grads = jax.tree.map(lambda g: comm.all_reduce(g) / n, grads)
+            grads = jax.tree.map(lambda g: g / n, all_reduce(grads))
             loss = jax.lax.psum(loss, axis) / n
         with jax.named_scope("train_optimizer"):
             new_params, new_opt, _ = adamw_update(opt_cfg, grads, params, opt_state)

@@ -7,9 +7,12 @@ device count at first init, so it cannot share this process.  Everything
 here is device-free: fingerprints, compiled tables vs the per-round
 reference, round-group folding, the slot-addressed all-to-all compile
 (checked by a pure-numpy emulation of the executor), LRU accounting, and
-the attributable trace-time errors.
+the attributable trace-time errors.  The shared round loop's device checks
+(a gradient tree against per-leaf all-reduces, the DP step, one-buffer HLO)
+run in shared_loop_check.py under 4 host devices, also in a subprocess.
 """
 
+import json
 import os
 import pathlib
 import subprocess
@@ -395,3 +398,45 @@ def test_exec_engine_device_checks():
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "ALL-EXEC-ENGINE-OK" in proc.stdout
+
+
+SHARED_LOOP_CHECKS = [
+    "tree_matches_per_leaf",
+    "tree_matches_reference",
+    "tree_mixed_paths",
+    "tree_eager",
+    "xla_and_sim_trees",
+    "dp_step_bitwise",
+    "dp_step_counter",
+    "one_buffer_hlo_ring_all_reduce",
+    "one_buffer_hlo_rhd_all_reduce",
+    "one_buffer_hlo_ring_all_gather",
+]
+
+
+@pytest.fixture(scope="module")
+def shared_loop_results():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "tests" / "shared_loop_check.py")],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return {r["check"]: r for r in map(json.loads, proc.stdout.strip().splitlines())}
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("check", SHARED_LOOP_CHECKS)
+def test_shared_round_loop_on_devices(shared_loop_results, check):
+    assert set(shared_loop_results) == set(SHARED_LOOP_CHECKS)
+    r = shared_loop_results[check]
+    assert r["ok"], r["detail"]
+
+
+def test_shared_loop_counter_surface():
+    exec_engine.clear_exec_caches()
+    assert exec_engine.exec_stats().shared_loop_buffers == 0
+    exec_engine.note_shared_loop(3)
+    assert exec_engine.exec_stats().shared_loop_buffers == 3
+    exec_engine.clear_exec_caches()
+    assert exec_engine.exec_stats().shared_loop_buffers == 0
