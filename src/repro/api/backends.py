@@ -126,16 +126,18 @@ class InterpBackend:
     derivation).  Called with concrete arrays, the backend instead routes
     through :func:`_run_eager`'s jitted-executable cache.
 
-    ``all_reduce`` also takes a pytree of arrays (a gradient tree).  Each
-    leaf is padded and chunked as alone, then the leaves are grouped by
-    the fingerprint of their compiled schedule, which ignores byte sizes,
-    and each group runs through one round loop shared by all its leaves
+    ``all_reduce`` also takes a pytree of arrays (a gradient tree); a lone
+    array is a tree of one leaf.  Each leaf is padded and chunked as
+    alone, then the leaves are grouped by the fingerprint of their
+    schedule, which ignores byte sizes, and each group goes to
+    :meth:`_run` together.  On an unsplit communicator a group runs
+    through one round loop shared by all its leaves
     (``exec_engine.execute_compiled`` given several buffers, counted in
     ``exec_stats().shared_loop_buffers``): one leaf's gathers and adds can
     then run under another's ``ppermute``.  Every leaf's result is
-    bit-identical to its own ``all_reduce``.  A lone array, a split
-    communicator and ``ring_ef8`` leaves take the one-array path; concrete
-    leaves outside a trace run eagerly one by one.
+    bit-identical to its own ``all_reduce``.  Split communicators and
+    ``ring_ef8`` run each leaf alone; concrete leaves outside a trace run
+    eagerly one by one.
     """
 
     name = "interp"
@@ -144,12 +146,10 @@ class InterpBackend:
         import jax
 
         leaves, treedef = jax.tree.flatten(x)
-        if jax.tree_util.treedef_is_leaf(treedef):
-            return self._collective(comm, "all_reduce", x)
         if all(_eager_eligible(leaf) for leaf in leaves):
             out = [_run_eager(comm, "all_reduce", leaf) for leaf in leaves]
         else:
-            out = self._traced_all_reduce_tree(comm, leaves)
+            out = self._traced(comm, "all_reduce", leaves, None)
         return treedef.unflatten(out)
 
     def reduce_scatter(self, comm, x):
@@ -165,62 +165,49 @@ class InterpBackend:
     def _collective(self, comm, collective, x):
         if _eager_eligible(x):
             return _run_eager(comm, collective, x)
-        return self._traced(comm, collective, x, None)
+        return self._traced(comm, collective, [x], None)[0]
 
-    def _traced(self, comm, collective, x, sched: "Optional[Schedule]"):
-        """Trace-time body; ``sched`` pre-resolved on the eager path (the
-        executable must run exactly the schedule its cache key names) and
-        the tree path.
-        Every op it adds, chunking and padding included, is named
+    def _traced(self, comm, collective, xs, sched: "Optional[Schedule]"):
+        """Trace-time body for the local operands ``xs``; returns their
+        results in order.  ``sched`` is pre-resolved on the eager path (the
+        executable must run exactly the schedule its cache key names).
+        Operands whose schedules share a fingerprint (which ignores byte
+        sizes) run together through :meth:`_run`.  Every op it adds,
+        chunking and padding included, is named
         ``pccl_<collective>_<algorithm>`` (``exec_engine.collective_scope``)."""
         from repro.comm import exec_engine
 
-        exec_engine.note_trace()
-        if sched is None:
-            sched = _traced_schedule(comm, collective, x)
-        with exec_engine.collective_scope(sched.collective, sched.algorithm):
-            if collective != "all_reduce":
-                return self._run(comm, collective, x, sched)
-            out = self._run(comm, "all_reduce", _padded_flat(comm, x), sched)
-            return _unpadded(out, x)
-
-    def _traced_all_reduce_tree(self, comm, leaves):
-        """Trace-time all-reduce of many leaves: those whose compiled
-        schedules share a fingerprint run through one round loop."""
-        from repro.comm import exec_engine
-
-        out = list(leaves)
-        shared = {}  # schedule fingerprint -> (schedule, leaf indices)
-        for i, x in enumerate(leaves):
-            sched = _traced_schedule(comm, "all_reduce", x)
-            if comm.groups is None and sched.algorithm != "ring_ef8":
-                shared.setdefault(sched.fingerprint(), (sched, []))[1].append(i)
-            else:
-                out[i] = self._traced(comm, "all_reduce", x, sched)
-        for sched, idx in shared.values():
-            for _ in idx:
-                exec_engine.note_trace()
-            with exec_engine.collective_scope(sched.collective, sched.algorithm):
-                chunks = [_padded_flat(comm, leaves[i]).reshape(comm.n, -1) for i in idx]
-                chunks = exec_engine.execute_compiled(
-                    chunks, exec_engine.compile_schedule(sched), comm.axis_name
-                )
-                for i, c in zip(idx, chunks):
-                    out[i] = _unpadded(c.reshape(-1), leaves[i])
+        shared = {}  # schedule fingerprint -> (schedule, operand indices)
+        for i, x in enumerate(xs):
+            exec_engine.note_trace()
+            s = sched if sched is not None else _traced_schedule(comm, collective, x)
+            shared.setdefault(s.fingerprint(), (s, []))[1].append(i)
+        out = list(xs)
+        for s, idx in shared.values():
+            with exec_engine.collective_scope(s.collective, s.algorithm):
+                ops = [xs[i] for i in idx]
+                if collective == "all_reduce":
+                    ops = [_padded_flat(comm, x) for x in ops]
+                for i, y in zip(idx, self._run(comm, collective, ops, s)):
+                    out[i] = _unpadded(y, xs[i]) if collective == "all_reduce" else y
         return out
 
     # -- dispatch: ungrouped → primitives; grouped → local-rank variants --
-    def _run(self, comm, collective, x, sched):
+    def _run(self, comm, collective, xs, sched):
+        """``sched`` run on each operand of ``xs``; ungrouped all-reduce
+        operands share one round loop (``primitives.all_reduce``)."""
         from repro.comm import primitives as P
 
-        if comm.groups is None:
-            if collective == "all_reduce" and sched.algorithm == "ring_ef8":
-                # planner-selected wire compression: int8 payloads per hop
-                from repro.comm.fusion import all_reduce_quantized
+        if comm.groups is not None:
+            return [_grouped_collective(comm, collective, x, sched) for x in xs]
+        if collective == "all_reduce" and sched.algorithm == "ring_ef8":
+            # planner-selected wire compression: int8 payloads per hop
+            from repro.comm.fusion import all_reduce_quantized
 
-                return all_reduce_quantized(x, sched, comm.axis_name)
-            return getattr(P, collective)(x, sched, comm.axis_name)
-        return _grouped_collective(comm, collective, x, sched)
+            return [all_reduce_quantized(x, sched, comm.axis_name) for x in xs]
+        if collective == "all_reduce":
+            return P.all_reduce(xs, sched, comm.axis_name)
+        return [getattr(P, collective)(x, sched, comm.axis_name) for x in xs]
 
 
 def _traced_schedule(comm, collective, x):
@@ -359,7 +346,7 @@ def _build_executable(comm, collective, sched, global_shape):
     axis = view.axis_name
 
     def inner(xl):
-        return backend._traced(view, collective, xl[0], sched)[None]
+        return backend._traced(view, collective, [xl[0]], sched)[0][None]
 
     mesh = Mesh(jax.devices()[: view.axis_size], (axis,))
     spec = P(axis, *([None] * (len(global_shape) - 1)))

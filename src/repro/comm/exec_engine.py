@@ -28,7 +28,8 @@ interpreter: the same integer chunk ids are gathered, permuted and
 scattered in the same order, so reductions see the same add order per
 receiver.  The ``lax.scan`` merely rolls the identical round body into a
 loop.  Several buffers that run one compiled schedule (the leaves of a
-gradient tree, from ``repro.api.backends``) can share that loop: each round
+gradient tree, which ``repro.api.backends`` hands
+``primitives.all_reduce`` as a list) can share that loop: each round
 group runs once, and its body is a software pipeline over the buffers,
 largest first — one ``ppermute`` in flight at a time, the next buffer's
 gather and the last buffer's add running under it.  Each buffer still

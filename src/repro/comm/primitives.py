@@ -54,15 +54,14 @@ def _round_tables(
     return round_tables(rnd, n, ctx=ctx)
 
 
-def execute_schedule(
-    chunks: jax.Array, schedule: Schedule, axis_name: str
-) -> jax.Array:
+def execute_schedule(chunks, schedule: Schedule, axis_name: str):
     """Run a schedule's rounds on a local chunk buffer inside ``shard_map``.
 
     Args:
       chunks: (n_chunks, *chunk_shape) local buffer; chunk ids as in the
         schedule (RS/AG: n_chunks == n; AllToAll: n_chunks == n with id
-        src*n+dst mapped to local block dst — see callers).
+        src*n+dst mapped to local block dst — see callers).  A list of
+        such buffers shares one round loop and gives a list back.
       schedule: permutation-round schedule from ``repro.core.schedules``.
       axis_name: mesh axis of size ``schedule.n``.
 
@@ -130,10 +129,15 @@ def all_gather(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Array:
     return chunks.reshape((n * x.shape[0],) + x.shape[1:])
 
 
-def all_reduce(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Array:
+def all_reduce(x, schedule: Schedule, axis_name: str):
     """x: full per-rank buffer. Returns sum over ranks, replicated.
-    The schedule must be an all_reduce composition (RS rounds + AG rounds)."""
+    The schedule must be an all_reduce composition (RS rounds + AG rounds).
+    ``x`` may be a list of buffers: they share one round loop, each with
+    the result it would get alone, and a list comes back."""
     n = schedule.n
+    if isinstance(x, (list, tuple)):
+        chunks = execute_schedule([_split_chunks(b, n) for b in x], schedule, axis_name)
+        return [c.reshape(b.shape) for c, b in zip(chunks, x)]
     chunks = _split_chunks(x, n)
     chunks = execute_schedule(chunks, schedule, axis_name)
     return chunks.reshape(x.shape)

@@ -200,8 +200,8 @@ class PerLeaf:
     def __init__(self, comm):
         self.comm, self.axis_name, self.n = comm, comm.axis_name, comm.n
 
-    def all_reduce(self, x):
-        return self.comm.all_reduce(x)
+    def all_reduce(self, tree):
+        return jax.tree.map(self.comm.all_reduce, tree)
 
 
 def dp_steps():
