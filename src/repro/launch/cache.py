@@ -17,7 +17,8 @@ REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 # that profiler traces are read by: without this, a program that names its
 # ops otherwise would load, and trace, an executable named the old way.
 # Change it whenever those names change.
-OP_NAMES = "train_forward train_optimizer train_grad_sync pccl_<collective>_<algorithm>/group<i>"
+OP_NAMES = ("train_forward train_optimizer train_grad_sync pccl_<collective>_<algorithm>/group<i>"
+            " encdec_encoder encdec_cross_attention")
 
 
 def enable_compile_cache() -> str:

@@ -47,6 +47,11 @@ from .module import Box, KeyGen, normal_init, stack_init
 
 Batch = Dict[str, jax.Array]
 
+# Named scopes of the encoder-decoder's parts, which profiler traces name
+# its ops by (bench/encdec_scopes.py reads them there)
+ENCODER_SCOPE = "encdec_encoder"
+CROSS_ATTENTION_SCOPE = "encdec_cross_attention"
+
 
 def _remat(fn, cfg: ModelConfig):
     if cfg.remat == "none":
@@ -116,12 +121,13 @@ def _apply_decoder_layer(
     a_out, new_cache = attn_fn(p["attn"], cfg, h, positions=positions, cache=cache, mode=mode)
     x = x + a_out
     if "xattn" in p:
-        h = apply_norm(p["ln_x"], x, eps=cfg.norm_eps, norm_type=cfg.norm_type)
-        if cross_kv is not None:
-            xa = A.apply_cross_attn_cached(p["xattn"], cfg, h, cross_kv)
-        else:
-            xa = A.apply_cross_attn(p["xattn"], cfg, h, enc)
-        x = x + xa
+        with jax.named_scope(CROSS_ATTENTION_SCOPE):
+            h = apply_norm(p["ln_x"], x, eps=cfg.norm_eps, norm_type=cfg.norm_type)
+            if cross_kv is not None:
+                xa = A.apply_cross_attn_cached(p["xattn"], cfg, h, cross_kv)
+            else:
+                xa = A.apply_cross_attn(p["xattn"], cfg, h, enc)
+            x = x + xa
     h = apply_norm(p["ln2"], x, eps=cfg.norm_eps, norm_type=cfg.norm_type)
     if kind == "moe":
         f_out, aux = M.apply_moe(p["ffn"], cfg, h)
@@ -362,7 +368,15 @@ def _apply_encoder_layer(p, cfg: ModelConfig, x):
 
 
 class EncDecLM(Model):
-    """Whisper-style: stubbed mel-frame embeddings → encoder → decoder LM."""
+    """Whisper-style: stubbed mel-frame embeddings → encoder → decoder LM.
+
+    The encoder (frames and positions through its layers and ``ln_enc``)
+    runs under ``jax.named_scope(ENCODER_SCOPE)``, and each decoder layer's
+    cross-attention (its norm, the K/V projection of the frames, the
+    attention and its residual add) and the serving path's precomputed
+    cross K/V under ``CROSS_ATTENTION_SCOPE``.  A scope sets HLO
+    ``op_name`` metadata and changes no instruction.
+    """
 
     def init(self, key):
         cfg = self.cfg
@@ -383,15 +397,16 @@ class EncDecLM(Model):
 
     def _encode(self, params, frames: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = frames.astype(cfg.act_dtype())
-        x = x + sinusoidal_positions(x.shape[1], cfg.d_model).astype(x.dtype)[None]
-        x = shard(x, ("batch", "seq", "act_embed"))
 
         def body(h, lp):
             return _apply_encoder_layer(lp, cfg, h), None
 
-        x, _ = _maybe_scan(cfg, _remat(body, cfg), x, params["enc_layers"])
-        return apply_norm(params["ln_enc"], x, eps=cfg.norm_eps, norm_type=cfg.norm_type)
+        with jax.named_scope(ENCODER_SCOPE):
+            x = frames.astype(cfg.act_dtype())
+            x = x + sinusoidal_positions(x.shape[1], cfg.d_model).astype(x.dtype)[None]
+            x = shard(x, ("batch", "seq", "act_embed"))
+            x, _ = _maybe_scan(cfg, _remat(body, cfg), x, params["enc_layers"])
+            return apply_norm(params["ln_enc"], x, eps=cfg.norm_eps, norm_type=cfg.norm_type)
 
     def _decode_stack(self, params, x, positions, enc, caches, mode, cross_kv=None):
         cfg = self.cfg
@@ -434,7 +449,6 @@ class EncDecLM(Model):
 
     def _cross_kv(self, params, enc: jax.Array):
         """Precompute per-layer cross-attention K/V from encoder output."""
-        cfg = self.cfg
         dt = enc.dtype
 
         def one(lp):
@@ -442,7 +456,8 @@ class EncDecLM(Model):
             v = jnp.einsum("btd,dhk->bthk", enc, lp["xattn"]["wv"].astype(dt))
             return {"k": k, "v": v}
 
-        return jax.vmap(one)(params["dec_layers"])
+        with jax.named_scope(CROSS_ATTENTION_SCOPE):
+            return jax.vmap(one)(params["dec_layers"])
 
     def init_decode_state(self, batch: int, max_len: int):
         cfg = self.cfg

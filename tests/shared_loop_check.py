@@ -21,6 +21,8 @@ at first init).  Prints one JSON line per check, ``{"check", "ok",
   gives the parameters, optimizer state and loss of the per-leaf call;
 * ``dp_step_counter``: ``shared_loop_buffers`` counts the step's leaves,
   and ``PcclSession.exec_stats()`` shows it;
+* ``dp_step_bitwise_encdec``, ``dp_step_counter_encdec``: the same two for
+  a small whisper-small, whose 32 leaves all share the loop;
 * ``one_buffer_hlo_<case>``: a one-buffer ``execute_compiled`` compiles to
   the optimised HLO of the engine before the shared loop existed (kept
   below as ``single_buffer_engine``), instruction names and metadata
@@ -33,7 +35,6 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=4 " + os.environ.get("XLA_FLAGS", "")
 )
 
-import dataclasses  # noqa: E402
 import json  # noqa: E402
 import re  # noqa: E402
 import traceback  # noqa: E402
@@ -50,6 +51,8 @@ from repro.comm import exec_engine  # noqa: E402
 from repro.comm import primitives as prim  # noqa: E402
 from repro.core import cost_model as cm  # noqa: E402
 from repro.core import schedules as S  # noqa: E402
+
+from check_helpers import canonical_hlo, small_model  # noqa: E402
 
 N = 4
 AXIS = "x"
@@ -204,41 +207,42 @@ class PerLeaf:
         return jax.tree.map(self.comm.all_reduce, tree)
 
 
-def dp_steps():
-    from repro.configs import get_config
-    from repro.models import build_model
+def dp_steps(name):
+    """One step of ``make_dp_train_step`` for a small ``name``, with the
+    tree call and with :class:`PerLeaf`; each result with the
+    ``shared_loop_buffers`` its trace added."""
     from repro.models.module import unbox
     from repro.train.optimizer import OptimizerConfig, init_opt_state
     from repro.train.train_step import make_dp_train_step
 
-    cfg = dataclasses.replace(get_config("bert-base-paper"), n_layers=2, d_model=32,
-                              n_heads=2, n_kv_heads=2, d_ff=64, vocab=128)
-    model = build_model(cfg)
+    model = small_model(name)
     params = unbox(model.init(jax.random.PRNGKey(0)))
     rng = np.random.default_rng(5)
     batch = {k: jnp.asarray(rng.integers(0, 128, size=(2 * N, 8)), jnp.int32)
              for k in ("tokens", "targets")}
+    if model.cfg.enc_dec is not None:
+        batch["enc_frames"] = jnp.asarray(rng.standard_normal((2 * N, 12, 32)), jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:N]), ("data",))
     session = PcclSession(cm.TPU_V5E_PHOTONIC, thread_fabric=False)
     comm = session.communicator("data", N, backend="interp")
     opt_cfg = OptimizerConfig()
     out = {}
-    for name, c in (("tree", comm), ("per_leaf", PerLeaf(comm))):
+    for kind, c in (("tree", comm), ("per_leaf", PerLeaf(comm))):
         step = make_dp_train_step(model, opt_cfg, c, mesh)
         before = session.exec_stats().shared_loop_buffers
         p = jax.tree.map(jnp.copy, params)
         res = step(p, init_opt_state(p), batch)
-        out[name] = (res, session.exec_stats().shared_loop_buffers - before)
+        out[kind] = (res, session.exec_stats().shared_loop_buffers - before)
     return out, len(jax.tree.leaves(params))
 
 
 _DP = {}
 
 
-def dp():
-    if not _DP:
-        _DP["out"], _DP["leaves"] = dp_steps()
-    return _DP["out"], _DP["leaves"]
+def dp(name="bert-base-paper"):
+    if name not in _DP:
+        _DP[name] = dp_steps(name)
+    return _DP[name]
 
 
 @check
@@ -252,6 +256,21 @@ def dp_step_bitwise():
 def dp_step_counter():
     out, leaves = dp()
     assert out["tree"][1] == leaves, (out["tree"][1], leaves)
+    assert out["per_leaf"][1] == 0, out["per_leaf"][1]
+    return {"leaves": leaves}
+
+
+@check
+def dp_step_bitwise_encdec():
+    out, _ = dp("whisper-small")
+    assert_trees_equal(out["tree"][0], out["per_leaf"][0])
+    return {"loss": float(out["tree"][0][2])}
+
+
+@check
+def dp_step_counter_encdec():
+    out, leaves = dp("whisper-small")
+    assert leaves == 32 and out["tree"][1] == leaves, (out["tree"][1], leaves)
     assert out["per_leaf"][1] == 0, out["per_leaf"][1]
     return {"leaves": leaves}
 
@@ -286,19 +305,6 @@ def single_buffer_engine(chunks, compiled, axis_name):
 
 
 _NAME = re.compile(r"%?[A-Za-z_][\w.\-]*(?=[ ,)(=\]}])")
-
-
-def canonical_hlo(text):
-    """Optimised HLO text without metadata, module name or instruction names."""
-    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
-    # the stack-frame tables that metadata points into
-    text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames|\d+ .*)\n", "", text,
-                  flags=re.M)
-    text = re.sub(r"^HloModule \S+", "HloModule m", text, flags=re.M)
-    names = {}
-    for line_name in re.findall(r"%([\w.\-]+)", text):
-        names.setdefault(line_name, f"v{len(names)}")
-    return re.sub(r"%([\w.\-]+)", lambda m: "%" + names[m.group(1)], text)
 
 
 def compiled_hlo(engine, sched, local_shape):

@@ -408,6 +408,8 @@ SHARED_LOOP_CHECKS = [
     "xla_and_sim_trees",
     "dp_step_bitwise",
     "dp_step_counter",
+    "dp_step_bitwise_encdec",
+    "dp_step_counter_encdec",
     "one_buffer_hlo_ring_all_reduce",
     "one_buffer_hlo_rhd_all_reduce",
     "one_buffer_hlo_ring_all_gather",

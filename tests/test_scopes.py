@@ -33,16 +33,22 @@ def steps():
     return {r["step"]: r for r in map(json.loads, proc.stdout.strip().splitlines())}
 
 
+PHASES = {"jvp(train_forward)", "transpose(jvp(train_forward))", "train_optimizer"}
+ENCDEC = {"encdec_encoder", "encdec_cross_attention"}
+ENCDEC_STEPS = ["whisper_single", "whisper_dp"]
+
+
 @pytest.mark.parametrize("step,scopes", [
-    ("single", {"jvp(train_forward)", "transpose(jvp(train_forward))", "train_optimizer"}),
-    ("dp", {"jvp(train_forward)", "transpose(jvp(train_forward))", "train_optimizer",
-            "train_grad_sync"}),
+    ("single", PHASES),
+    ("dp", PHASES | {"train_grad_sync"}),
+    ("whisper_single", PHASES | ENCDEC),
+    ("whisper_dp", PHASES | ENCDEC | {"train_grad_sync"}),
 ])
 def test_step_carries_every_phase_scope(steps, step, scopes):
     assert scopes <= set(steps[step]["scopes"])
 
 
-@pytest.mark.parametrize("step", ["single", "dp"])
+@pytest.mark.parametrize("step", ["single", "dp", *ENCDEC_STEPS])
 def test_nearly_every_instruction_falls_under_a_scope(steps, step):
     r = steps[step]
     assert r["covered"] >= 0.98, r["unscoped"]
@@ -83,3 +89,37 @@ def test_collective_scope_does_not_nest_in_itself():
     assert "pccl_all_reduce_ring/mul" in text
     assert "pccl_all_reduce_ring/pccl_all_reduce_ring" not in text
     assert "pccl_all_reduce_ring/pccl_all_gather_rhd/sub" in text
+
+
+@pytest.mark.parametrize("step", ENCDEC_STEPS)
+@pytest.mark.parametrize("scope", sorted(ENCDEC))
+def test_encdec_parameters_are_read_under_their_scope(steps, step, scope):
+    """Every instruction that computes with the encoder's parameters
+    (``enc_layers``, ``ln_enc``), or with the cross-attention's
+    (``xattn``), runs under that scope, the AdamW update aside; in a build
+    without the scopes the same count falls outside it."""
+    r = steps[step]["readers"][scope]
+    assert r["other"] == [], r["other"]
+    assert r["under"] > 0 and r["optimizer"] > 0, r
+    assert steps[step]["readers_without_scopes"][scope] == r["under"]
+
+
+@pytest.mark.parametrize("step", ENCDEC_STEPS)
+def test_encdec_scopes_do_not_nest_in_themselves(steps, step):
+    assert steps[step]["nesting"] == {s: 1 for s in ENCDEC}
+
+
+@pytest.mark.parametrize("step", ENCDEC_STEPS)
+def test_encdec_scopes_change_no_instruction(steps, step):
+    # the optimised HLO of the small step equals that of a build in which
+    # the two scopes open nothing, instruction names and metadata aside
+    assert steps[step]["hlo_equal_without_scopes"]
+
+
+def test_encdec_dp_step_permutes_are_its_round_groups(steps):
+    # all 32 gradient leaves through the engine: one ppermute per leaf and
+    # round group, each under that group's scope
+    r = steps["whisper_dp"]
+    assert "" not in r["permutes"], r["permutes"]
+    assert r["permutes"] == r["planned_groups"]
+    assert sum(r["planned_groups"].values()) == 32 * len(r["planned_groups"])
