@@ -127,17 +127,30 @@ class InterpBackend:
     through :func:`_run_eager`'s jitted-executable cache.
 
     ``all_reduce`` also takes a pytree of arrays (a gradient tree); a lone
-    array is a tree of one leaf.  Each leaf is padded and chunked as
-    alone, then the leaves are grouped by the fingerprint of their
-    schedule, which ignores byte sizes, and each group goes to
-    :meth:`_run` together.  On an unsplit communicator a group runs
-    through one round loop shared by all its leaves
+    array is a tree of one leaf.  The leaves are grouped by the
+    fingerprint of their schedule, which ignores byte sizes, and each
+    group goes to :meth:`_run` together.  On an unsplit communicator a
+    group runs through one round loop shared by all its leaves
     (``exec_engine.execute_compiled`` given several buffers, counted in
     ``exec_stats().shared_loop_buffers``): one leaf's gathers and adds can
     then run under another's ``ppermute``.  Every leaf's result is
     bit-identical to its own ``all_reduce``.  Split communicators and
     ``ring_ef8`` run each leaf alone; concrete leaves outside a trace run
     eagerly one by one.
+
+    How an all-reduce operand is chunked (:func:`_chunked_layout`): on an
+    unsplit communicator, outside ``ring_ef8``, an operand of two or more
+    dimensions keeps its own shape and is cut into the schedule's ``n``
+    chunks along its leading axis (counted in
+    ``exec_stats().layout_kept_buffers``).  The TPU tiles an array's two
+    minor dimensions, so that split is a bitcast both ways, and the result
+    reaches its consumer (AdamW, in the DP step) in the leaf's own layout;
+    a flattened leaf would need a physical relayout on the way in and
+    again on the way out.  Scalars, 1-D operands, ``ring_ef8`` (whose int8
+    scale is shared by a flat payload) and split communicators keep the
+    flat layout.  Where the leading axis divides by ``n``, chunk ``c``
+    holds the elements flat chunk ``c`` would, so the result is
+    bit-identical to the flat layout's.
     """
 
     name = "interp"
@@ -187,7 +200,9 @@ class InterpBackend:
             with exec_engine.collective_scope(s.collective, s.algorithm):
                 ops = [xs[i] for i in idx]
                 if collective == "all_reduce":
-                    ops = [_padded_flat(comm, x) for x in ops]
+                    keep = comm.groups is None and s.algorithm != "ring_ef8"
+                    ops = [_chunked_layout(comm.n, x, keep) for x in ops]
+                    exec_engine.note_layout_kept(sum(y.ndim >= 2 for y in ops))
                 for i, y in zip(idx, self._run(comm, collective, ops, s)):
                     out[i] = _unpadded(y, xs[i]) if collective == "all_reduce" else y
         return out
@@ -217,22 +232,50 @@ def _traced_schedule(comm, collective, x):
     )
 
 
-def _padded_flat(comm, x):
-    """``x`` flattened and zero-padded to a multiple of ``comm.n``."""
-    flat = x.reshape(-1)
-    pad = (-flat.size) % comm.n
-    if pad:
-        import jax.numpy as jnp
+def _sublane_rows(dtype) -> int:
+    """Rows of one TPU tile of ``dtype``: 8 of 32-bit words, packed 16 of
+    16-bit and 32 of 8-bit elements."""
+    return 8 * max(1, 4 // dtype.itemsize)
 
+
+def _chunked_layout(n: int, x, keep: bool):
+    """The all-reduce operand for ``x``, in the layout its ``n`` chunks
+    take along its leading axis (``exec_engine.split_chunks``).
+
+    With ``keep`` (an unsplit communicator, not ``ring_ef8``), an ``x`` of
+    two or more dimensions keeps its shape and so its TPU layout: the TPU
+    tiles the two minor dimensions, so splitting the leading axis into
+    ``(n, rows / n)`` is a bitcast.  Leading rows are zero-padded where
+    needed: with three or more dimensions to a multiple of ``n``; with two,
+    where ``rows`` does not divide by ``n``, to a multiple of ``n`` tiles of
+    rows, so that each chunk holds whole tiles.  A 2-D ``x`` whose rows
+    divide by ``n`` is not padded (its chunks then hold the elements of
+    the flat chunks, so the result stays bit-identical to the flat
+    layout's).  Otherwise ``x`` is flattened and zero-padded to a multiple
+    of ``n``; :func:`exec_engine._lane_view` lays the flat chunks out.
+    """
+    import jax.numpy as jnp
+
+    if keep and x.ndim >= 2:
+        rows = x.shape[0]
+        step = n if x.ndim > 2 or rows % n == 0 else n * _sublane_rows(x.dtype)
+        pad = (-rows) % step
+        if pad:
+            x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
+        return x
+    flat = x.reshape(-1)
+    pad = (-flat.size) % n
+    if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
     return flat
 
 
-def _unpadded(flat, x):
-    """The all-reduced ``flat`` cut back to ``x``'s size and shape."""
-    if flat.size != x.size:
-        flat = flat[: x.size]
-    return flat.reshape(x.shape)
+def _unpadded(y, x):
+    """The all-reduced operand ``y`` cut back to ``x``: the pad rows off a
+    kept layout, the pad off a flat one."""
+    if y.ndim != x.ndim:
+        return y[: x.size].reshape(x.shape)
+    return y[: x.shape[0]] if y.shape != x.shape else y
 
 
 # ------------------------------------------------------------- eager path
@@ -240,7 +283,8 @@ def _unpadded(flat, x):
 
 def _local_nbytes(comm, collective, local_shape, itemsize: int) -> float:
     """The nbytes a collective of a local operand is planned for (an
-    all-reduce operand padded to a multiple of ``comm.n``)."""
+    all-reduce operand as its flat size padded to a multiple of
+    ``comm.n``, whatever layout :func:`_chunked_layout` then gives it)."""
     import math
 
     size = math.prod(local_shape) if local_shape else 1

@@ -29,7 +29,8 @@ scattered in the same order, so reductions see the same add order per
 receiver.  The ``lax.scan`` merely rolls the identical round body into a
 loop.  Several buffers that run one compiled schedule (the leaves of a
 gradient tree, which ``repro.api.backends`` hands
-``primitives.all_reduce`` as a list) can share that loop: each round
+``primitives.all_reduce`` as a list, each of two or more dimensions
+carried in its own shape) can share that loop: each round
 group runs once, and its body is a software pipeline over the buffers,
 largest first — one ``ppermute`` in flight at a time, the next buffer's
 gather and the last buffer's add running under it.  Each buffer still
@@ -42,8 +43,9 @@ name, and ``repro.api.backends`` opens it around a collective's chunking
 and padding too.
 
 Caches and counters (compiled-table LRU, the jitted-executable LRU that
-``repro.api.backends`` fills, the trace counter and the count of buffers
-traced through a shared round loop) are process-wide,
+``repro.api.backends`` fills, the trace counter, the count of buffers
+traced through a shared round loop and of all-reduce operands that kept
+their own layout) are process-wide,
 lock-guarded and surfaced through :func:`exec_stats` /
 ``PcclSession.exec_stats()``.  This module imports JAX lazily — planning-
 and sim-only processes can read stats without touching it.
@@ -81,9 +83,11 @@ __all__ = [
     "execute_compiled",
     "note_fallback_dispatch",
     "note_fused_dispatch",
+    "note_layout_kept",
     "note_shared_loop",
     "note_trace",
     "round_tables",
+    "split_chunks",
 ]
 
 
@@ -397,11 +401,15 @@ _LANE = 128  # TPU vector lane width
 def _lane_view(chunks):
     """``(k, m)`` chunk buffer → ``(k, m // _LANE, _LANE)`` where ``m`` allows.
 
-    A flat buffer split into ``k`` chunks is a 2-D array whose
-    second-minor dimension is the small chunk count; the TPU compiler
-    tiles that layout badly, and its compile time grows with ``m`` (about
-    25 s at 200 MB per chunk buffer on v5e).  The 3-D view keeps every
-    element in its chunk and position, so results stay bit-identical.
+    For flat chunk buffers: an all-reduce operand that is a scalar or 1-D,
+    or runs ``ring_ef8`` or on a split communicator (one of two or more
+    dimensions otherwise keeps its own layout,
+    ``repro.api.backends._chunked_layout``).  A flat buffer split into
+    ``k`` chunks is a 2-D array whose second-minor dimension is the small
+    chunk count; the TPU compiler tiles that layout badly, and its compile
+    time grows with ``m`` (about 25 s at 200 MB per chunk buffer on v5e).
+    The 3-D view keeps every element in its chunk and position, so
+    results stay bit-identical.
     """
     if chunks.ndim == 2 and chunks.shape[1] > _LANE and chunks.shape[1] % _LANE == 0:
         return chunks.reshape(chunks.shape[0], chunks.shape[1] // _LANE, _LANE)
@@ -434,11 +442,21 @@ def collective_scope(collective: str, algorithm: str):
         _SCOPE.name = outer
 
 
-def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=None):
+def execute_compiled(
+    chunks, compiled: CompiledSchedule, axis_name: str, *, me=None, operands=False
+):
     """Run a compiled schedule on local chunk buffers inside ``shard_map``.
 
     ``chunks`` is one buffer, or a list or tuple of buffers that all run
-    ``compiled``; the result has the same form.  Several buffers share one
+    ``compiled``; the result has the same form.  With ``operands``, the
+    buffers are the collective's operands, whose leading axes split into
+    the schedule's ``n`` chunks (:func:`split_chunks`): the loop carries
+    one of two or more dimensions in its own shape and views it as
+    ``(n, rows / n, …)`` inside each round, a bitcast on the TPU, whose
+    tiles span the two minor dimensions.  The carry then keeps the layout
+    the operand's producer gave it; carried as chunks, it would take the
+    default layout and need a copy to enter the loop.  A 1-D operand is
+    split and runs as a flat chunk buffer.  Several buffers share one
     round loop: each round group runs once, and its body gathers, permutes
     and adds or sets every buffer, largest first.  An
     ``optimization_barrier`` holds each ``ppermute`` until the previous
@@ -467,16 +485,22 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
     if me is None:
         me = lax.axis_index(axis_name)
     shapes = [b.shape for b in bufs]
+    views = [None] * len(bufs)  # a buffer's chunks inside a round, if carried whole
+    if operands:
+        split = [split_chunks(b, compiled.n) for b in bufs]
+        views = [c.shape if b.ndim >= 2 else None for b, c in zip(bufs, split)]
+        bufs = tuple(b if b.ndim >= 2 else c for b, c in zip(bufs, split))
 
     # largest first: each transfer then lasts at least as long as the next
     # buffer's gather, and the previous buffer's add hides under it unless
     # that buffer is several times larger
     order = sorted(range(len(bufs)), key=lambda j: -bufs[j].size)
 
-    def apply_round(bufs, send, recv, grp):
+    def apply_round(carry, send, recv, grp):
         def land(buf, got):
             return buf.at[recv].add(got) if grp.reduce else buf.at[recv].set(got)
 
+        bufs = [b if v is None else b.reshape(v) for b, v in zip(carry, views)]
         out, prev, got = list(bufs), None, None
         for j in order:
             payload = jnp.take(bufs[j], send, axis=0)
@@ -487,10 +511,10 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
                 out[prev] = land(bufs[prev], got)
             got, prev = lax.ppermute(payload, axis_name, grp.perm), j
         out[prev] = land(bufs[prev], got)
-        return tuple(out)
+        return tuple(o.reshape(c.shape) for o, c in zip(out, carry))
 
     with collective_scope(compiled.collective, compiled.algorithm):
-        bufs = tuple(_lane_view(b) for b in bufs)
+        bufs = tuple(b if v else _lane_view(b) for b, v in zip(bufs, views))
         for i, grp in enumerate(compiled.groups):
             with jax.named_scope(f"group{i}"):
                 send = jnp.take(jnp.asarray(grp.send_ids), me, axis=1)  # (rounds, k)
@@ -505,6 +529,15 @@ def execute_compiled(chunks, compiled: CompiledSchedule, axis_name: str, *, me=N
                     bufs, _ = lax.scan(body, bufs, (send, recv))
         out = [b.reshape(shape) for b, shape in zip(bufs, shapes)]
         return type(chunks)(out) if many else out[0]
+
+
+def split_chunks(x, n: int):
+    """``x`` as ``n`` chunks along its leading axis: ``(n, rows / n, …)``."""
+    if x.shape[0] % n:
+        raise ScheduleExecutionError(
+            f"leading dim {x.shape[0]} not divisible by {n} ranks"
+        )
+    return x.reshape((n, x.shape[0] // n) + x.shape[1:])
 
 
 def execute_all_to_all_compact(blocks, compiled: CompiledSchedule, axis_name: str, me):
@@ -571,6 +604,7 @@ EXECUTABLES = _LruCache(max_entries=128)  # exec key → jitted callable
 _TRACE_LOCK = threading.Lock()
 _TRACES = 0
 _SHARED_LOOP_BUFFERS = 0
+_LAYOUT_KEPT_BUFFERS = 0
 
 # Dispatch counters, filled by repro.comm.fusion: dispatches that streamed
 # producer tiles into collective rounds vs. took the sequential fallback.
@@ -593,6 +627,13 @@ def note_shared_loop(buffers: int) -> None:
     global _SHARED_LOOP_BUFFERS
     with _TRACE_LOCK:
         _SHARED_LOOP_BUFFERS += buffers
+
+
+def note_layout_kept(buffers: int) -> None:
+    """Record ``buffers`` all-reduce operands chunked in their own layout."""
+    global _LAYOUT_KEPT_BUFFERS
+    with _TRACE_LOCK:
+        _LAYOUT_KEPT_BUFFERS += buffers
 
 
 def note_fused_dispatch() -> None:
@@ -623,6 +664,7 @@ class ExecStats:
     fused_dispatches: int = 0
     fallback_dispatches: int = 0
     shared_loop_buffers: int = 0
+    layout_kept_buffers: int = 0
 
 
 def exec_stats() -> ExecStats:
@@ -637,11 +679,14 @@ def exec_stats() -> ExecStats:
     * ``shared_loop_buffers`` — buffers traced through a round loop shared
       with at least one other buffer (:func:`execute_compiled` given
       several); counted at trace time, like ``traces``.
+    * ``layout_kept_buffers`` — all-reduce operands that entered the round
+      loop in their own shape, chunked along the leading axis, instead of
+      flattened (``repro.api.backends._chunked_layout``); at trace time.
     * ``fused_*``/``fallback_*`` — dispatch counters from
       ``repro.comm.fusion`` (see :func:`note_fused_dispatch`).
     """
     with _TRACE_LOCK:
-        traces, shared = _TRACES, _SHARED_LOOP_BUFFERS
+        traces, shared, kept = _TRACES, _SHARED_LOOP_BUFFERS, _LAYOUT_KEPT_BUFFERS
     with _OVERLAP_LOCK:
         fused, fallback = _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
     return ExecStats(
@@ -655,6 +700,7 @@ def exec_stats() -> ExecStats:
         fused_dispatches=fused,
         fallback_dispatches=fallback,
         shared_loop_buffers=shared,
+        layout_kept_buffers=kept,
     )
 
 
@@ -667,11 +713,12 @@ def clear_exec_caches() -> None:
     loaded: importing it here would pull JAX into planning-/sim-only
     processes that this module deliberately keeps JAX-free.
     """
-    global _TRACES, _SHARED_LOOP_BUFFERS, _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
+    global _TRACES, _SHARED_LOOP_BUFFERS, _LAYOUT_KEPT_BUFFERS
+    global _FUSED_DISPATCHES, _FALLBACK_DISPATCHES
     _COMPILED.clear()
     EXECUTABLES.clear()
     with _TRACE_LOCK:
-        _TRACES = _SHARED_LOOP_BUFFERS = 0
+        _TRACES = _SHARED_LOOP_BUFFERS = _LAYOUT_KEPT_BUFFERS = 0
     with _OVERLAP_LOCK:
         _FUSED_DISPATCHES = _FALLBACK_DISPATCHES = 0
     lint = sys.modules.get("repro.analysis.kernel_lint")

@@ -510,9 +510,9 @@ def all_reduce_quantized(x, schedule, axis_name: str):
     ungrouped all_reduce here whenever the planned schedule's algorithm is
     ``ring_ef8``.
     """
-    from .primitives import _split_chunks
+    from .exec_engine import split_chunks
 
     compiled = exec_engine.compile_schedule(schedule)
-    chunks = _split_chunks(x, schedule.n)
+    chunks = split_chunks(x, schedule.n)
     chunks = execute_compiled_quantized(chunks, compiled, axis_name)
     return chunks.reshape(x.shape)

@@ -44,6 +44,7 @@ from .exec_engine import (
     execute_all_to_all_compact,
     execute_compiled,
     round_tables,
+    split_chunks,
 )
 
 
@@ -102,19 +103,11 @@ def execute_schedule_reference(
 # --------------------------------------------------------------------------
 
 
-def _split_chunks(x: jax.Array, n: int) -> jax.Array:
-    if x.shape[0] % n:
-        raise ScheduleExecutionError(
-            f"leading dim {x.shape[0]} not divisible by {n} ranks"
-        )
-    return x.reshape((n, x.shape[0] // n) + x.shape[1:])
-
-
 def reduce_scatter(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Array:
     """x: full per-rank buffer (each rank holds its own addend).
     Returns this rank's fully reduced chunk (1/n of the buffer)."""
     n = schedule.n
-    chunks = _split_chunks(x, n)
+    chunks = split_chunks(x, n)
     chunks = execute_schedule(chunks, schedule, axis_name)
     me = lax.axis_index(axis_name)
     return jnp.take(chunks, me, axis=0)
@@ -133,14 +126,10 @@ def all_reduce(x, schedule: Schedule, axis_name: str):
     """x: full per-rank buffer. Returns sum over ranks, replicated.
     The schedule must be an all_reduce composition (RS rounds + AG rounds).
     ``x`` may be a list of buffers: they share one round loop, each with
-    the result it would get alone, and a list comes back."""
-    n = schedule.n
-    if isinstance(x, (list, tuple)):
-        chunks = execute_schedule([_split_chunks(b, n) for b in x], schedule, axis_name)
-        return [c.reshape(b.shape) for c, b in zip(chunks, x)]
-    chunks = _split_chunks(x, n)
-    chunks = execute_schedule(chunks, schedule, axis_name)
-    return chunks.reshape(x.shape)
+    the result it would get alone, and a list comes back.  A buffer of two
+    or more dimensions is carried through the loop in its own shape
+    (``execute_compiled(..., operands=True)``)."""
+    return execute_compiled(x, compile_schedule(schedule), axis_name, operands=True)
 
 
 def all_to_all(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Array:
@@ -157,7 +146,7 @@ def all_to_all(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Array:
     compact = compile_all_to_all(schedule, n, tuple(range(n)))
     if compact is None:
         return all_to_all_dense(x, schedule, axis_name)
-    blocks = _split_chunks(x, n)                       # (n, blk, …) dest-major
+    blocks = split_chunks(x, n)                       # (n, blk, …) dest-major
     me = lax.axis_index(axis_name)
     return execute_all_to_all_compact(blocks, compact, axis_name, me).reshape(x.shape)
 
@@ -174,7 +163,7 @@ def run_reference(
     n = schedule.n
     me = lax.axis_index(axis_name)
     if collective == "reduce_scatter":
-        chunks = _split_chunks(x, n)
+        chunks = split_chunks(x, n)
         chunks = execute_schedule_reference(chunks, schedule, axis_name)
         return jnp.take(chunks, me, axis=0)
     if collective == "all_gather":
@@ -182,11 +171,11 @@ def run_reference(
         chunks = execute_schedule_reference(chunks, schedule, axis_name)
         return chunks.reshape((n * x.shape[0],) + x.shape[1:])
     if collective == "all_reduce":
-        chunks = _split_chunks(x, n)
+        chunks = split_chunks(x, n)
         chunks = execute_schedule_reference(chunks, schedule, axis_name)
         return chunks.reshape(x.shape)
     if collective == "all_to_all":
-        blocks = _split_chunks(x, n)
+        blocks = split_chunks(x, n)
         state = jnp.zeros((n, n) + blocks.shape[1:], blocks.dtype)
         state = state.at[me].set(blocks)
         flat = state.reshape((n * n,) + blocks.shape[1:])
@@ -203,7 +192,7 @@ def all_to_all_dense(x: jax.Array, schedule: Schedule, axis_name: str) -> jax.Ar
     memory, but exact for *any* schedule semantics (arbitrarily many blocks
     in flight from different origins can coexist at one rank)."""
     n = schedule.n
-    blocks = _split_chunks(x, n)                       # (n, blk, …) dest-major
+    blocks = split_chunks(x, n)                       # (n, blk, …) dest-major
     me = lax.axis_index(axis_name)
     # state[o, t] = block from origin o to target t, held locally (zeros if
     # not present). Initially we hold row `me`.

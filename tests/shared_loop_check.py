@@ -11,7 +11,17 @@ at first init).  Prints one JSON line per check, ``{"check", "ok",
   (leaves that need padding, a single element, bf16, two communicators
   with different algorithms so that two loops run) is bit-identical to
   ``comm.all_reduce`` of each leaf;
-* ``tree_matches_reference``: ... and to ``primitives.run_reference``;
+* ``tree_matches_reference``: ... and to ``primitives.run_reference`` on
+  each leaf laid out as the backend lays it out;
+* ``layout_kept_float32``, ``layout_kept_bfloat16``: leaves of shapes
+  ``(12, 8, 256)``, ``(37, 256)`` (padded rows), ``(12, 64)``, ``(24,)``
+  and ``()``, through a ring and an rhd loop, equal ``lax.psum`` within
+  the summation's rounding, are bit-identical to the flat layout's
+  result (``run_reference`` on the flattened leaf) where the leading axis
+  divides by ``n``, and ``layout_kept_buffers`` counts the leaves of two
+  or more dimensions;
+* ``layout_kept_split_and_ef8_flat``: split communicators and ``ring_ef8``
+  keep every leaf flat;
 * ``tree_mixed_paths``: a tree whose leaves plan two algorithms, one of
   them the per-leaf ``ring_ef8``, still matches leaf by leaf;
 * ``tree_eager``: a concrete tree outside any trace runs leaf by leaf;
@@ -103,14 +113,34 @@ def assert_trees_equal(a, b):
         assert np.array_equal(x.view(np.uint8), y.view(np.uint8)), "not bit-identical"
 
 
-def reference_leaf(comm, x):
-    """The pre-engine interpreter on one leaf, padded as the backend pads."""
+def reference_flat(comm, x):
+    """The pre-engine interpreter on one leaf, flattened and padded to a
+    multiple of ``n``: the layout every leaf had before leaves of two or
+    more dimensions kept their own."""
     flat = x.reshape(-1)
     pad = (-flat.size) % comm.n
     flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)]) if pad else flat
     sched = comm.axis_schedule("all_reduce", flat.size * flat.dtype.itemsize)
     out = prim.run_reference("all_reduce", flat, sched, AXIS)
     return out[: x.size].reshape(x.shape)
+
+
+def reference_leaf(comm, x):
+    """The pre-engine interpreter on one leaf, laid out as the backend lays
+    it out: with two or more dimensions in its own shape, chunked along
+    its leading rows, which are zero-padded to a multiple of ``n`` (with
+    three or more dimensions) or, where a 2-D leaf's rows do not divide by
+    ``n``, of ``n`` tiles of 8 32-bit rows; else flattened."""
+    if x.ndim < 2:
+        return reference_flat(comm, x)
+    rows = x.shape[0]
+    tile = 8 * max(1, 4 // x.dtype.itemsize)
+    step = comm.n if x.ndim > 2 or rows % comm.n == 0 else comm.n * tile
+    pad = (-rows) % step
+    y = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)]) if pad else x
+    padded_size = x.size + (-x.size) % comm.n  # planned as flat, as the backend plans
+    sched = comm.axis_schedule("all_reduce", padded_size * x.dtype.itemsize)
+    return prim.run_reference("all_reduce", y, sched, AXIS)[:rows]
 
 
 # ------------------------------------------------------------ tree checks
@@ -141,6 +171,67 @@ def tree_matches_reference():
     # and it is the sum over ranks
     np.testing.assert_allclose(np.asarray(got["ring"]["padded"][0]),
                                tree["padded"].sum(axis=0), rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------- leaves in their own layout
+KEPT_SHAPES = [(12, 8, 256), (37, 256), (12, 64), (24,), ()]  # (37, 256): padded rows
+
+
+def kept_tree(rng, dtype):
+    return [rng.normal(size=(N,) + s).astype(dtype) for s in KEPT_SHAPES]
+
+
+def kept_check(dtype):
+    """A tree of the shapes above in ``dtype``, through both loops: equal to
+    ``lax.psum`` within the summation's rounding; bit-identical to the flat
+    layout's result where the leading axis divides by ``n``; and
+    ``layout_kept_buffers`` counts the leaves of two or more dimensions."""
+    def run():
+        tree = kept_tree(np.random.default_rng(7), dtype)
+        before = exec_engine.exec_stats().layout_kept_buffers
+        got = run_tree(lambda t: {k: c.all_reduce(t) for k, c in TWO_LOOPS.items()}, tree)
+        counted = exec_engine.exec_stats().layout_kept_buffers - before
+        kept = sum(len(s) >= 2 for s in KEPT_SHAPES)
+        assert counted == len(TWO_LOOPS) * kept, (counted, kept)
+        psum = run_tree(lambda t: jax.tree.map(lambda x: lax.psum(x, AXIS), t), tree)
+        flat = run_tree(lambda t: {k: [reference_flat(c, x) for x in t]
+                                   for k, c in TWO_LOOPS.items()}, tree)
+        # each sum of N terms is within (N - 1) eps sum|x| of the exact one
+        eps = float(jnp.finfo(dtype).eps)
+        bound = [2 * (N - 1) * eps * np.abs(x.astype(np.float32)).sum(axis=0) for x in tree]
+        identical = []
+        for k in TWO_LOOPS:
+            for shape, g, p, f, b in zip(KEPT_SHAPES, got[k], psum, flat[k], bound):
+                g32, p32 = np.asarray(g, np.float32)[0], np.asarray(p, np.float32)[0]
+                assert g.shape == (N,) + shape and g.dtype == dtype, (g.shape, g.dtype)
+                assert np.all(np.abs(g32 - p32) <= b), (k, shape, np.abs(g32 - p32).max())
+                if shape and shape[0] % N == 0:
+                    assert_trees_equal(g, f)
+                    identical.append(shape)
+        return {"layout_kept_buffers": counted, "bit_identical_to_flat": identical}
+
+    run.__name__ = f"layout_kept_{np.dtype(dtype).name}"
+    return run
+
+
+for _dtype in (np.float32, jnp.bfloat16):
+    check(kept_check(_dtype))
+
+
+@check
+def layout_kept_split_and_ef8_flat():
+    """Split communicators and ``ring_ef8`` keep the flat layout."""
+    tree = kept_tree(np.random.default_rng(8), np.float32)
+    split = comm_of("ring").split([0, 0, 1, 1])
+    ef8 = comm_of("ring_ef8", cm.H100_DGX, rel_error_tol=0.05)
+    before = exec_engine.exec_stats().layout_kept_buffers
+    got = run_tree(lambda t: [split.all_reduce(t), ef8.all_reduce(t)], tree)
+    assert exec_engine.exec_stats().layout_kept_buffers == before
+    for x, g in zip(tree, got[0]):  # groups {0, 1} and {2, 3}
+        pair = x.reshape((2, 2) + x.shape[1:]).sum(axis=1)
+        np.testing.assert_allclose(np.asarray(g), np.repeat(pair, 2, axis=0),
+                                   rtol=1e-5, atol=1e-5)
+    return {"leaves": len(tree)}
 
 
 @check

@@ -403,6 +403,9 @@ def test_exec_engine_device_checks():
 SHARED_LOOP_CHECKS = [
     "tree_matches_per_leaf",
     "tree_matches_reference",
+    "layout_kept_float32",
+    "layout_kept_bfloat16",
+    "layout_kept_split_and_ef8_flat",
     "tree_mixed_paths",
     "tree_eager",
     "xla_and_sim_trees",
@@ -442,3 +445,12 @@ def test_shared_loop_counter_surface():
     assert exec_engine.exec_stats().shared_loop_buffers == 3
     exec_engine.clear_exec_caches()
     assert exec_engine.exec_stats().shared_loop_buffers == 0
+
+
+def test_layout_kept_counter_surface():
+    exec_engine.clear_exec_caches()
+    assert exec_engine.exec_stats().layout_kept_buffers == 0
+    exec_engine.note_layout_kept(12)
+    assert exec_engine.exec_stats().layout_kept_buffers == 12
+    exec_engine.clear_exec_caches()
+    assert exec_engine.exec_stats().layout_kept_buffers == 0
