@@ -3,8 +3,8 @@
   PYTHONPATH=src python examples/serve_decode.py --arch zamba2-2.7b
   PYTHONPATH=src python examples/serve_decode.py --arch deepseek-v2-lite-16b
 
-(reduced configs — same code paths the decode_32k / long_500k dry-run cells
-lower at full scale, including MLA absorbed decode and SSM state decode)
+(reduced configs — the same code paths as at full scale, including MLA
+absorbed decode and SSM state decode)
 """
 
 import argparse

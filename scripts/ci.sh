@@ -1,14 +1,14 @@
 #!/usr/bin/env bash
 # Tier-1 verification in named stages (see ROADMAP.md).
 #
-#   scripts/ci.sh                    # all stages: lint verify smoke tests bench
-#   scripts/ci.sh lint smoke         # just these stages, in order
+#   scripts/ci.sh                    # all stages: lint verify tests
+#   scripts/ci.sh lint verify        # just these stages, in order
 #   scripts/ci.sh tests -- -k session  # stage args after -- go to pytest
 #   scripts/ci.sh -k session         # back-compat: bare pytest args run all
 #                                    # stages with those args forwarded
 #
 # Stages (the GitHub Actions workflow runs them as separate steps so a
-# compileall or smoke failure fails fast before paying for the full suite):
+# compileall or verify failure fails fast before paying for the full suite):
 #   lint   - byte-compile everything + refuse tracked bytecode +
 #            concurrency lint (repro.analysis.lint_concurrency) + ruff
 #            (style/import order; skipped gracefully where not installed)
@@ -17,22 +17,15 @@
 #            realizability, plan/concurrent-plan accounting invariants,
 #            plus the Pallas kernel analyzer (--kernels): coverage,
 #            write-race, bounds and scratch-carry proofs per pallas_call
-#   smoke  - planner/exec/concurrent bench smoke guards (deterministic
-#            regression checks + loose wall-clock bars); writes fresh
-#            point JSONs into .ci-bench/ for the bench stage
 #   tests  - the full pytest suite (hypothesis property suites run when
 #            requirements-dev.txt is installed; they auto-skip otherwise)
-#   bench  - scripts/bench_gate.py: fresh .ci-bench/ speedups vs the
-#            committed BENCH_*.json baselines (documented tolerance)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-BENCH_DIR=".ci-bench"
-
 stage_lint() {
   # fast lint: every module must at least byte-compile
-  python -m compileall -q src benchmarks scripts tests
+  python -m compileall -q src scripts tests
   # committed bytecode must never reappear (purged in PR 5; see .gitignore)
   if git ls-files | grep -E '(^|/)__pycache__/|\.pyc$'; then
     echo "lint: tracked bytecode detected — purge it and rely on .gitignore" >&2
@@ -44,7 +37,7 @@ stage_lint() {
   # style/import-order lint; requirements-dev installs ruff in CI, but the
   # dev image may not have it — degrade to a notice rather than fail
   if command -v ruff >/dev/null 2>&1; then
-    ruff check src benchmarks scripts tests
+    ruff check src scripts tests
   else
     echo "lint: ruff not installed; skipping style checks (CI runs them)"
   fi
@@ -59,54 +52,10 @@ stage_verify() {
   python -m repro.analysis --kernels
 }
 
-stage_smoke() {
-  mkdir -p "$BENCH_DIR"
-  # planner perf smoke: plan_sweep must stay bit-identical to the per-size
-  # plan() loop and meaningfully faster (n=16), and one n=256 hierarchical
-  # point per case must plan cold inside its wall-clock bar (keeps the
-  # scaling path alive in CI without the full n=1024 matrix)
-  python -m benchmarks.planner_bench --smoke --json-out "$BENCH_DIR/BENCH_planner.json"
-  # execution-engine smoke (n=8): warm engine calls must be 0-retrace
-  # (deterministic guard) and beat the cold per-round interpreter; also
-  # runs one fused comm/compute point (tile-streaming matmul+RS at n=8,
-  # 512x128x128) asserting bit-identity to the sequential composition and
-  # a >=1.3x warm-dispatch win — the fusion acceptance bar
-  python -m benchmarks.exec_bench --smoke --json-out "$BENCH_DIR/BENCH_exec.json"
-  # concurrent-group smoke (n=16): joint plans reproducible, never worse
-  # than sequential, >= 1.2x at some swept point
-  python -m benchmarks.concurrent_bench --smoke --json-out "$BENCH_DIR/BENCH_concurrent.json"
-  # serving control-plane smoke (tp=4 x dp=4): arbiter >= 1.2x FIFO p99 at
-  # some operating point, never worse anywhere, and at 2x overload shedding
-  # engages with admitted-request p99 still bounded
-  python -m benchmarks.serve_bench --smoke --json-out "$BENCH_DIR/BENCH_serve.json"
-}
-
 stage_tests() {
   # --durations keeps slow planner tests visible as the suite grows
   # ${arr[@]+...} keeps `set -u` happy on bash < 4.4 when no args were given
   python -m pytest -x -q --durations=10 ${PYTEST_ARGS[@]+"${PYTEST_ARGS[@]}"}
-}
-
-stage_bench() {
-  # regenerate any fresh file the smoke stage did not leave behind
-  mkdir -p "$BENCH_DIR"
-  [ -f "$BENCH_DIR/BENCH_planner.json" ] || \
-    python -m benchmarks.planner_bench --smoke --json-out "$BENCH_DIR/BENCH_planner.json"
-  [ -f "$BENCH_DIR/BENCH_exec.json" ] || \
-    python -m benchmarks.exec_bench --smoke --json-out "$BENCH_DIR/BENCH_exec.json"
-  [ -f "$BENCH_DIR/BENCH_concurrent.json" ] || \
-    python -m benchmarks.concurrent_bench --smoke --json-out "$BENCH_DIR/BENCH_concurrent.json"
-  [ -f "$BENCH_DIR/BENCH_serve.json" ] || \
-    python -m benchmarks.serve_bench --smoke --json-out "$BENCH_DIR/BENCH_serve.json"
-  # exec gets a looser tolerance: its warm-leg denominator is milliseconds
-  # and legitimately swings under co-tenant load (see bench_gate docstring);
-  # serve gets a tighter 0.5: its speedups are ratios of planned costs on
-  # seeded traces (machine-independent), only the smoke trace length differs
-  python scripts/bench_gate.py \
-    "$BENCH_DIR/BENCH_planner.json:BENCH_planner.json" \
-    "$BENCH_DIR/BENCH_exec.json:BENCH_exec.json:0.1" \
-    "$BENCH_DIR/BENCH_concurrent.json:BENCH_concurrent.json" \
-    "$BENCH_DIR/BENCH_serve.json:BENCH_serve.json:0.5"
 }
 
 # ---- argument parsing: stage names, then optional -- pytest args ----------
@@ -119,7 +68,7 @@ for arg in "$@"; do
     PYTEST_ARGS+=("$arg")
   else
     case "$arg" in
-      lint|verify|smoke|tests|bench) STAGES+=("$arg") ;;
+      lint|verify|tests) STAGES+=("$arg") ;;
       *)
         # back-compat with the pre-stage interface: the first word that is
         # not a stage name (a pytest flag, test path, -k expression, ...)
@@ -131,7 +80,7 @@ for arg in "$@"; do
   fi
 done
 if [ "${#STAGES[@]}" -eq 0 ]; then
-  STAGES=(lint verify smoke tests bench)
+  STAGES=(lint verify tests)
 fi
 
 for stage in "${STAGES[@]}"; do

@@ -18,7 +18,7 @@ Three implementations of one protocol:
   (what ``PcclComm(algorithm="xla")`` used to spell as a string hack).
 * ``sim``    — cost-model-only: data passes through with single-copy
   placeholder semantics while the *planned* time of every collective is
-  accumulated on ``elapsed_s``.  Lets benchmarks and the serve/launch layers
+  accumulated on ``elapsed_s``.  Lets tests and the serve/launch layers
   drive the identical Communicator API with no devices at all.
 
 JAX is imported lazily so a ``sim``-only process never touches it.

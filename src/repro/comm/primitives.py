@@ -12,7 +12,7 @@ per-round tables once (memoized process-wide by ``Schedule.fingerprint()``)
 and folds runs of rounds sharing a permutation into a single ``lax.scan`` —
 same chunk metadata, same add order, bit-identical outputs, O(round-groups)
 trace size.  ``execute_schedule_reference`` keeps the original per-round
-interpreter as the engine's equivalence oracle (tests, benchmarks).
+interpreter as the engine's equivalence oracle (tests).
 
 ``all_to_all`` uses the engine's slot-addressed compile: local state is one
 ``(n, blk)`` buffer — O(n·blk) memory — whenever the chunk metadata admits
@@ -79,8 +79,8 @@ def execute_schedule_reference(
     """Pre-engine per-round interpreter — the engine's bit-identity oracle.
 
     Re-derives static tables per round per trace and emits one ppermute +
-    scatter pair per round with no fusion.  Kept for equivalence tests and
-    the ``exec_bench`` old-vs-new comparison; use ``execute_schedule``.
+    scatter pair per round with no fusion.  Kept for equivalence tests;
+    use ``execute_schedule``.
     """
     n = schedule.n
     me = lax.axis_index(axis_name)
@@ -157,8 +157,8 @@ def run_reference(
     """Whole-collective pre-engine interpreter — the bit-identity oracle.
 
     The original wrappers verbatim over :func:`execute_schedule_reference`
-    (dense all-to-all state included); shared by the equivalence tests and
-    ``benchmarks/exec_bench.py`` so the oracle exists exactly once.
+    (dense all-to-all state included); shared by the equivalence tests so
+    the oracle exists exactly once.
     """
     n = schedule.n
     me = lax.axis_index(axis_name)

@@ -1,21 +1,17 @@
-"""Architecture registry: ``get_config("<arch-id>")`` (+ ``SHAPES``)."""
+"""Architecture registry: ``get_config("<arch-id>")``."""
 
 from importlib import import_module
 from typing import Dict, List
 
 from .base import (
-    SHAPES,
     EncDecConfig,
     HybridConfig,
     MLAConfig,
     ModelConfig,
     MoEConfig,
-    ShapeConfig,
     SSMConfig,
     VLMConfig,
     XLSTMConfig,
-    cells_for,
-    shape_applicable,
 )
 
 _MODULES = {

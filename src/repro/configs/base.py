@@ -1,20 +1,15 @@
-"""Config system: model architecture + input-shape registry.
+"""Config system: model architecture dataclasses.
 
 Every assigned architecture gets a module ``configs/<id>.py`` exporting
 ``CONFIG`` (exact published numbers) built on these dataclasses.  Each config
 can derive a ``reduced()`` variant — same family and code paths, tiny sizes —
-used by CPU smoke tests; the full config is only ever lowered via
-ShapeDtypeStructs in the dry-run.
-
-The four assigned input shapes live in ``SHAPES``; applicability per arch
-(decode vs train vs long-context) is resolved by :func:`cells_for`.
+used by CPU smoke tests.
 """
 
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 import jax.numpy as jnp
 
@@ -110,15 +105,6 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch decode at 500 K context (SSM/linear/hybrid state)?"""
-        return self.family in ("ssm", "hybrid")
-
-    @property
-    def has_decoder(self) -> bool:
-        return True  # all assigned archs are decoder-bearing
-
     def act_dtype(self):
         return jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32
 
@@ -160,31 +146,3 @@ class ModelConfig:
         if self.vlm:
             kw["vlm"] = VLMConfig(n_img_tokens=8)
         return replace(self, **kw)
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # train | prefill | decode
-
-
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """(runs?, reason-if-skipped). long_500k needs sub-quadratic attention
-    (DESIGN.md §3.2); all assigned archs have decoders so decode shapes run."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "pure full-attention arch: 500k decode needs sub-quadratic attention"
-    return True, ""
-
-
-def cells_for(cfg: ModelConfig) -> List[Tuple[ShapeConfig, bool, str]]:
-    return [(s, *shape_applicable(cfg, s)) for s in SHAPES.values()]

@@ -1,6 +1,6 @@
 """bert_base: the paper's own end-to-end workload (§6): 12L 16H d_model=2048
 transformer trained with FlexFlow-style simulation. We model it as a dense
-decoder with GELU MLP (d_ff=4*d) for the task-graph benchmarks."""
+decoder with GELU MLP (d_ff=4*d); the bert.* benchmark cells run it."""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

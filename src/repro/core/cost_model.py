@@ -11,7 +11,7 @@ on the candidate topology via BFS shortest paths, then
 over rounds).  A transfer with no path returns the large penalty.
 
 Hardware presets carry the constants used in the paper's evaluation (§5) and
-the TPU-v5e adaptation target used by the launch/roofline stack.
+an assumed TPU-v5e photonic target (its α/β are set, not measured).
 """
 
 from __future__ import annotations

@@ -742,7 +742,7 @@ def clear_schedule_cache() -> None:
 
 def get_schedule(collective: str, algorithm: str, n: int, d: float,
                  dims: Optional[Sequence[int]] = None) -> Schedule:
-    """Uniform constructor used by the planner facade and benchmarks.
+    """Uniform constructor used by the planner facade and the tests.
 
     Memoized: repeated lookups of the same (collective, algorithm, n, d,
     dims) return one shared immutable Schedule object."""

@@ -117,7 +117,7 @@ def ssd_decode_step(
     Bm: jax.Array,           # (B,N) or (B,H,N)
     Cm: jax.Array,           # (B,N) or (B,H,N)
 ) -> Tuple[jax.Array, jax.Array]:
-    """Single recurrent step: O(1) in context length (long_500k decode)."""
+    """Single recurrent step: O(1) in context length."""
     f32 = jnp.float32
     if Bm.ndim == 2:
         Bm = Bm[:, None, :]

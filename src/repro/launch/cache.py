@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache for this repo's programs.
 
 Entry points (``chip_smoke.py``, the train and serve CLIs, the DP example,
-``benchmarks/exec_bench.py``) call :func:`enable_compile_cache` once at
+``bench/run.py``) call :func:`enable_compile_cache` once at
 start-up, before their first compile.  Library code and tests never do.
 """
 

@@ -3,8 +3,7 @@
 Three execution modes per layer:
 * train     — full causal attention, no cache (flash kernel when enabled);
 * prefill   — causal attention that also materializes the KV cache;
-* decode    — one query token against a fixed-capacity cache (the assigned
-              decode_32k / long_500k shapes lower this path).
+* decode    — one query token against a fixed-capacity cache.
 
 MLA decode uses the *absorbed* formulation: queries are projected into the
 KV-LoRA space so the cache stores only (c_kv, k_rope) — the paper-level

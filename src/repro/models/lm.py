@@ -3,15 +3,13 @@
 ``build_model(cfg)`` returns a :class:`Model` exposing:
 
 * ``init(key)``                          → Box param tree (values + logical axes)
-* ``loss(params, batch, rng)``           → (scalar loss, metrics)     [train_4k]
+* ``loss(params, batch, rng)``           → (scalar loss, metrics)
 * ``prefill(params, batch)``             → (last-pos logits, decode state)
-                                                                      [prefill_32k]
-* ``decode_step(params, state, tokens)`` → (logits, new state)        [decode_32k,
-                                                                       long_500k]
+* ``decode_step(params, state, tokens)`` → (logits, new state)
 * ``init_decode_state(batch, max_len)``  → zeroed cache/state tree
 
 Layer stacks are ``lax.scan``-ed over stacked parameters (one compiled layer
-body regardless of depth — essential for 88-layer dry-run compiles), with
+body regardless of depth, so compile time does not grow with layers), with
 ``jax.checkpoint`` remat around the train body.  Heterogeneous stacks
 (DeepSeek dense layer 0, xLSTM sLSTM cadence, Zamba2 shared-attention cadence)
 scan over repeating *groups*.
@@ -63,8 +61,7 @@ def _remat(fn, cfg: ModelConfig):
 
 def _maybe_scan(cfg: ModelConfig, body, carry, xs):
     """lax.scan over stacked layer params, or a Python unroll when
-    cfg.scan_layers=False (the roofline path: XLA cost analysis counts while
-    bodies once, so exact FLOP/byte accounting needs unrolled modules)."""
+    cfg.scan_layers=False."""
     if cfg.scan_layers:
         return jax.lax.scan(body, carry, xs)
     n = jax.tree.leaves(xs)[0].shape[0]

@@ -35,7 +35,7 @@ class Box:
 
 # Box is a pytree node carrying its axes as static aux data, so init
 # functions can run under jax.eval_shape / jit and still return Box trees
-# (the dry-run never materializes full-model parameters).
+# (shape-only lowering never materializes full-model parameters).
 jax.tree_util.register_pytree_node(
     Box,
     lambda b: ((b.value,), b.axes),

@@ -3,8 +3,8 @@
 All three expose the same three-mode interface as attention layers:
 * ``train/prefill`` — chunkwise-parallel over the sequence (SSD scan);
   prefill also returns the recurrent state so decode can continue from it;
-* ``decode`` — O(1)-per-token recurrent update (this is what makes the
-  long_500k cells *runnable* for the ssm/hybrid archs — DESIGN.md §3.2).
+* ``decode`` — O(1)-per-token recurrent update (what makes 500 K-token
+  decode tractable for the ssm/hybrid archs — DESIGN.md §3.2).
 
 Deviation notes (DESIGN.md §3.1): mLSTM uses a sigmoid input gate instead of
 the exp-gate + m-stabilizer (same state-space form, numerically robust in

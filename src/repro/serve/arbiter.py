@@ -36,8 +36,8 @@ control plane around it:
 
 Time is *virtual*: the arbiter advances its clock by each planned round's
 cost (plus an optional fixed overhead), so behavior is deterministic and
-benchmarks replay identical traces.  See ``benchmarks/serve_bench.py`` for
-the arbiter-vs-FIFO comparison and README.md § "Serving control plane" for
+a replayed trace gives identical figures (``tests/test_serve_arbiter.py``
+holds the overload guard).  See README.md § "Serving control plane" for
 the lifecycle diagram.
 """
 
@@ -143,9 +143,6 @@ class ArbiterConfig:
     fused_dispatch: bool = False   # rounds dispatch through fused kernels
     prefill_lead_rounds: int = 1   # compute lead before prefill's first AR
     round_overhead_s: float = 0.0  # fixed per-round control overhead
-    serialize_rounds: bool = False  # charge rounds at the sequential
-    # (one-collective-at-a-time) cost — models a fabric-unaware scheduler;
-    # the FIFO baseline in benchmarks/serve_bench.py sets this
 
     def __post_init__(self) -> None:
         if self.queue_bound < 1:
@@ -351,8 +348,7 @@ class FabricArbiter:
             kinds = [k for k in KINDS if batches[k]]
             reqs = [self._collective_for(k, batches[k]) for k in kinds]
             cp = self._plan(reqs, self._offsets_for(kinds))
-        executed_s = cp.sequential_cost if self.cfg.serialize_rounds else cp.cost
-        round_s = executed_s + self.cfg.round_overhead_s
+        round_s = cp.cost + self.cfg.round_overhead_s
         self.clock += round_s
         self._busy_s += round_s
         self.rounds += 1

@@ -2,8 +2,8 @@
 
 A minimal continuous-batching-shaped engine: requests are admitted into a
 fixed-size batch, prefilled together, then decoded step-by-step; finished
-sequences free their slots.  The decode step is the same ``serve_step`` the
-dry-run lowers for decode_32k / long_500k.
+sequences free their slots.  The decode step is the model's jitted
+``decode_step``.
 
 With ``EngineConfig.tp > 1`` the engine also accounts for the tensor-parallel
 activation all-reduces through the PCCL session API (``sim`` backend: the

@@ -1,5 +1,5 @@
-"""Train/serve step builders — the functions the launcher jits and the
-dry-run lowers.
+"""Train/serve step builders — the functions the launcher and the benchmark
+cells jit.
 
 ``make_train_step`` supports microbatch gradient accumulation (sequential
 ``lax.scan`` over microbatches — the standard memory/throughput trade) and

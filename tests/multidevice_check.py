@@ -2,9 +2,8 @@
 
 Run as a subprocess by test_comm_multidevice.py with 8 host devices (this
 must set XLA_FLAGS before importing jax, which pytest's process cannot do
-without polluting single-device tests — see the dry-run rule in the
-assignment).  Asserts every schedule-driven collective matches the XLA
-reference collective bit-for-bit in fp32.
+without polluting single-device tests).  Asserts every schedule-driven
+collective matches the XLA reference collective bit-for-bit in fp32.
 """
 
 import os

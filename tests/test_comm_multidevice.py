@@ -1,8 +1,8 @@
 """Runs the 8-device collective equivalence suite in a subprocess.
 
 XLA locks the host device count at first jax init, so multi-device checks
-must not share a process with the single-device smoke tests (assignment
-rule: only the dry-run and dedicated subprocesses force device_count)."""
+must not share a process with the single-device smoke tests (only
+dedicated subprocesses force device_count)."""
 
 import os
 import pathlib

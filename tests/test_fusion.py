@@ -16,9 +16,6 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-ROOT = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(ROOT))  # benchmarks/ is a root-level namespace pkg
-
 from repro.comm import exec_engine
 from repro.comm.fusion import _stream_program, stream_program
 from repro.core import cost_model as cm
@@ -26,6 +23,7 @@ from repro.core import schedules as S
 from repro.core.cost_model import compressed_ef_error_bound
 from repro.core.pccl import candidate_algorithms
 
+ROOT = Path(__file__).resolve().parents[1]
 _D = float(1 << 20)
 
 
@@ -258,80 +256,6 @@ class TestMatmulKernel:
         with pytest.raises(ValueError):
             matmul_pallas(x, jnp.zeros((128, 100), jnp.float32),
                           block_n=64)  # N=100 not tiled
-
-
-# --------------------------------------------- taskgraph overlap modeling
-class TestTaskgraphOverlap:
-    def _sim(self, **kw):
-        from benchmarks.taskgraph import CommScheme, Workload, simulate_training
-        from repro.core import topology as T
-
-        return simulate_training(
-            Workload(), CommScheme("pccl", "pccl"), T.ring(8),
-            cm.TPU_V5E_PHOTONIC, **kw,
-        )
-
-    def test_default_and_zero_fraction_unchanged(self):
-        base = self._sim()
-        zero = self._sim(overlap_fraction=0.0)
-        assert zero.iteration_s == base.iteration_s
-        assert zero.comm_s == base.comm_s
-
-    def test_overlap_hides_comm_not_compute(self):
-        base = self._sim()
-        ov = self._sim(overlap_fraction=0.43)
-        full = self._sim(overlap_fraction=1.0)
-        assert ov.comm_s < base.comm_s
-        assert full.comm_s <= ov.comm_s
-        assert ov.compute_s == base.compute_s
-        assert ov.iteration_s == pytest.approx(ov.comm_s + ov.compute_s)
-        # the cold layer-1 AllReduce and one warm AllReduce never hide
-        assert full.comm_s > 0
-
-    def test_fraction_validated(self):
-        with pytest.raises(ValueError):
-            self._sim(overlap_fraction=1.5)
-
-    def test_measured_overlap_fraction(self, tmp_path):
-        import json
-
-        from benchmarks.taskgraph import measured_overlap_fraction
-
-        p = tmp_path / "BENCH_exec.json"
-        p.write_text(json.dumps({"points": [
-            {"collective": "fused_matmul_reduce_scatter",
-             "seq_warm_s": 10.0, "fused_warm_s": 6.0},
-            {"collective": "fused_matmul_reduce_scatter",
-             "seq_warm_s": 10.0, "fused_warm_s": 4.0},
-            {"collective": "reduce_scatter", "speedup": 100.0},
-        ]}))
-        assert measured_overlap_fraction(p) == pytest.approx(0.6)
-        p.write_text(json.dumps({"points": [{"collective": "all_gather"}]}))
-        assert measured_overlap_fraction(p) is None
-
-    def test_committed_bench_has_fused_rows(self):
-        # the committed baseline must keep feeding the overlap model
-        from benchmarks.taskgraph import measured_overlap_fraction
-
-        frac = measured_overlap_fraction(ROOT / "BENCH_exec.json")
-        assert frac is not None and 0.0 < frac < 1.0
-
-
-# --------------------------------------------------- bench gate schema
-def test_bench_gate_identifies_fused_rows():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate", ROOT / "scripts" / "bench_gate.py"
-    )
-    gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(gate)
-    assert "shape" in gate.ID_KEYS and "mode" in gate.ID_KEYS
-    a = {"n": 8, "collective": "fused_matmul_reduce_scatter",
-         "shape": "256x128x128", "mode": "fused", "speedup": 1.4}
-    b = dict(a, shape="512x128x128")
-    assert gate.point_id(a) != gate.point_id(b)
-    assert gate.point_id(a) == gate.point_id(dict(a, speedup=9.9))
 
 
 # ------------------------------------------------------- device subprocess

@@ -266,9 +266,8 @@ def test_session_replan_is_warm_and_permanent():
     )
     warm_routes = STRUCTURE_TABLE.stats.routing_calls - before
     # warm path re-routes only states the dead link touched: a small
-    # fraction of the cold structure phase (the >=10x wall-clock claim is
-    # measured in benchmarks/planner_bench.py; this is its deterministic
-    # routing-work proxy)
+    # fraction of the cold structure phase (routing calls are the
+    # deterministic proxy for the planner's wall-clock work)
     assert warm_routes <= 0.2 * cold_routes, (warm_routes, cold_routes)
 
     # permanence: fabric, initial fabric and standards all lost the link

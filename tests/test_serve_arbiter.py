@@ -5,6 +5,7 @@ Everything runs in virtual time on the sim/planning path: no devices, no
 wall clocks, so every scenario is deterministic."""
 
 import math
+import random
 
 import pytest
 
@@ -202,6 +203,123 @@ def test_fail_link_on_bare_session():
     fail_link(sess, 2, 3)
     edges = sess.fabric(8).edges
     assert (2, 3) not in edges and (3, 2) not in edges
+
+
+# ---------------------------------------------------------- overload guard
+# A seeded serving stream at twice the fabric's probed capacity: decode
+# waves, prefill bursts over mixed contexts and KV-cache migrations, all
+# priced in virtual time by one cost model, so every figure is exact.
+TP = DP = 4
+OVERLOAD_D_MODEL = 1024
+CONTEXTS = (128, 512, 2048)
+QUEUE_BOUND = 64
+MAX_BATCH = 8
+SEED = 20260807
+
+
+def _session() -> PcclSession:
+    return PcclSession(cm.H100_DGX, g0=T.ring(N))
+
+
+def _mix(rng: random.Random):
+    r = rng.random()
+    if r < 0.70:
+        return DECODE, 1
+    if r < 0.92:
+        return PREFILL, rng.choice(CONTEXTS)
+    return KV_MIGRATION, rng.choice(CONTEXTS)
+
+
+def _probe():
+    """``(round_s, capacity_rps)`` of the saturated fabric: drain a backlog
+    of the stream's own mix with no deadlines, no shedding."""
+    rng = random.Random(SEED ^ 0xBEEF)
+    arb = FabricArbiter(
+        _session(), tp=TP, dp=DP, d_model=OVERLOAD_D_MODEL,
+        cfg=ArbiterConfig(queue_bound=10_000, max_batch=MAX_BATCH,
+                          sla=SlaTarget(1e6, 1e6, 1e6), preemption=False),
+    )
+    for _ in range(120):
+        arb.submit(arb.make_request(*_mix(rng)))
+    while arb.queue_depth:
+        arb.tick()
+    rep = arb.report()
+    return rep["clock_s"] / rep["rounds"], rep["completed"] / rep["clock_s"]
+
+
+def _sla(round_s: float) -> SlaTarget:
+    """Decode within a few rounds, prefill within a batch drain, KV slack."""
+    return SlaTarget(prefill_s=12.0 * round_s, decode_s=3.0 * round_s,
+                     kv_migration_s=40.0 * round_s)
+
+
+def _poisson_trace(n_events: int, rate: float, seed: int):
+    rng = random.Random(seed)
+    t, events = 0.0, []
+    for _ in range(n_events):
+        t += rng.expovariate(rate)
+        events.append((t, *_mix(rng)))
+    return events
+
+
+def _bursty_trace(n_events: int, rate: float, seed: int):
+    """Same mean rate in phases of 20 arrivals: 4x hot, 0.4x cold."""
+    rng = random.Random(seed)
+    t, events = 0.0, []
+    for i in range(n_events):
+        hot = (i // 20) % 2 == 0
+        t += rng.expovariate(rate * (4.0 if hot else 0.4))
+        events.append((t, *_mix(rng)))
+    return events
+
+
+def _run_trace(events, arb: FabricArbiter) -> FabricArbiter:
+    """Drain the rounds due before each arrival, idle across gaps, then
+    drain the rest."""
+    for t, kind, ctx in events:
+        while arb.queue_depth and arb.clock < t:
+            arb.tick()
+        if arb.clock < t:
+            arb.tick(now=t)
+        arb.submit(arb.make_request(kind, ctx, arrival_s=t))
+    while arb.queue_depth:
+        arb.tick()
+    return arb
+
+
+def _pct(lats, p: float) -> float:
+    lats = sorted(lats)
+    return lats[min(len(lats) - 1, int(p * len(lats)))] if lats else float("nan")
+
+
+def _token_latencies(arb: FabricArbiter):
+    return [o.latency_s for o in arb.outcomes
+            if o.status == "completed" and o.kind == DECODE]
+
+
+@pytest.mark.parametrize("trace", [_poisson_trace, _bursty_trace],
+                         ids=["poisson", "bursty"])
+def test_overload_sheds_and_bounds_admitted_p99(trace):
+    """At 2x the probed capacity the arbiter sheds, the admitted decode
+    p99 stays within twice the slowest SLA target, and a replay of the
+    same point gives the same figures."""
+    round_s, capacity = _probe()
+    sla = _sla(round_s)
+    events = trace(150, 2.0 * capacity, SEED)
+
+    def serve():
+        arb = FabricArbiter(
+            _session(), tp=TP, dp=DP, d_model=OVERLOAD_D_MODEL,
+            cfg=ArbiterConfig(queue_bound=QUEUE_BOUND, max_batch=MAX_BATCH,
+                              sla=sla),
+        )
+        rep = _run_trace(events, arb).report()
+        return _pct(_token_latencies(arb), 0.99), rep["shed_rate"], rep["completed"]
+
+    p99, shed_rate, completed = serve()
+    assert shed_rate > 0.0
+    assert p99 <= 2.0 * max(sla.prefill_s, sla.decode_s, sla.kv_migration_s)
+    assert serve() == (p99, shed_rate, completed)
 
 
 # ------------------------------------------------------- EngineConfig split
